@@ -581,7 +581,7 @@ def placement_sweep_jax(n_containers: int = 2000, days: int = 3):
 def placement_sweep_pallas(n_containers: int = 384, days: int = 2):
     """Pallas admission-kernel dispatch check: `plan_jax` with
     `admission_impl="pallas"` (interpret mode on CPU — the same kernel
-    Mosaic compiles on TPU/GPU) vs the NumPy planner, tight capacity so
+    Mosaic compiles on a TPU) vs the NumPy planner, tight capacity so
     every epoch exercises the ranked-admission rounds.
 
     Headline numbers: `assign_equal` / `parity_max_abs_diff` /
@@ -640,49 +640,23 @@ def placement_sweep_pallas(n_containers: int = 384, days: int = 2):
     return rows, derived
 
 
-def jax_sweep_scale(n_traces: int = 100_000, n_targets: int = 10,
-                    days: int = 1):
-    """The N=1M placed fleet sweep: n_traces x n_targets containers
-    (1,000,000 at the defaults), one day at 5-minute epochs, through the
-    full jax path — vectorized trace generation, the capacity-planned
-    region schedule (`plan_jax`), and the memory-lean indexed-carbon
-    fleet scan (compact demand + in-step target tiling; no (T, N) array
-    on host or device) — with the carbon-aware traffic subsystem folded
-    in: a 1M-user request population is routed and autoscaled per epoch
-    and modulates every container's demand, the virtual energy supply
-    layer runs the host supply ledger on the compact fleet (solar +
-    battery + grid with a mid-day regional outage; cap_frac applied on
-    host, carbon billed at the delivered mix through the indexed
-    (c_eff, codes) layout so no (T, N) carbon matrix appears), and the
-    per-container elasticity layer runs its own compact-width scan (the
-    (N·K,) marginal-allocation argsort per epoch, under a shaped fleet
-    carbon budget) whose served demand feeds the fleet scan. A
-    signal-plane fault plan is enabled throughout: carbon-feed dropouts
-    plus a fleet-wide blackout window degraded through the
-    hold/prior/floor ladder, power-meter gaps (unmetered emissions
-    surfaced per row), and seeded migration failures with capped
-    exponential backoff in the planner. The 4 GB RSS ceiling holds with
-    all three layers AND the fault plan enabled, and the energy
-    invariants (conservation, zero cap/SoC violations) gate alongside
-    the throughput floor.
-
-    Headline numbers: `container_epochs_per_s` = N * T / steady_s
-    (steady state: second sweep call, jit cache warm), `warmup_s`
-    (first call, includes compile AND the placement plan),
-    `over_capacity_epochs` (the plan is recomputed once outside the
-    timed region for the invariant check — plans are deterministic, so
-    it is the same plan the sweep used). NumPy comparison is deliberately
-    absent: the fleet backend needs the ~2.3 GB tiled matrices and tens
-    of minutes at this N — parity is pinned at 50k by
-    tests/test_placement_scale.py instead.
-    """
+def fleet_1m_spec(n_traces: int = 100_000, n_targets: int = 10,
+                  days: int = 1, backend: str = "jax"):
+    """The `make jax-sweep` configuration as a `SweepSpec`: n_traces
+    Azure-like traces x n_targets carbon targets (N = 1,000,000 at the
+    defaults), 5-minute epochs over `days`, R = 3 regions (PL, NL,
+    CAISO), capacity-planned placement at 60% of the traces, a 1M-user
+    traffic layer, K = 4 elasticity under a shaped budget, the energy
+    supply with one outage and one carbon shock, and a signal-plane
+    fault plan (20% carbon-feed dropouts plus a blackout, power-meter
+    gaps, migration failures). Everything is generated from seeds."""
     from repro.carbon.intensity import TraceProvider
     from repro.cluster.placement import PlacementConfig, PlacementEngine
-    from repro.cluster.placement_jax import plan_jax
     from repro.cluster.slices import paper_family
     from repro.core.elasticity import ElasticityConfig
     from repro.core.policy import CarbonContainerPolicy
-    from repro.core.simulator import SimConfig, sweep_population
+    from repro.core.simulator import SimConfig
+    from repro.core.spec import SweepSpec
     from repro.energy import EnergyConfig, GridEventConfig
     from repro.robustness import (CarbonFeedFaults, DegradeConfig,
                                   FaultPlan, MigrationFaults,
@@ -695,17 +669,11 @@ def jax_sweep_scale(n_traces: int = 100_000, n_targets: int = 10,
     regions = ("PL", "NL", "CAISO")
     provs = [TraceProvider.for_region(r, hours=24 * days, seed=1)
              for r in regions]
-    t0 = time.perf_counter()
     demand = sample_population_matrix(n_traces, days=days, seed=2)
-    gen_s = time.perf_counter() - t0
     cap = int(np.ceil(0.6 * n_traces))
     eng = PlacementEngine(
         fam, provs, region_names=regions,
         config=PlacementConfig(capacity=cap, min_dwell=6, hysteresis=0.10))
-    targets = list(np.linspace(20.0, 80.0, n_targets))
-    policies = {"carbon_containers":
-                lambda: CarbonContainerPolicy(variant="energy")}
-    cfg = SimConfig(target_rate=0.0)
     traffic = TrafficConfig(
         population=UserPopulation(n_users=1_000_000, n_regions=3, seed=3),
         replicas=ReplicaConfig(max_replicas=8, max_step=4))
@@ -718,9 +686,8 @@ def jax_sweep_scale(n_traces: int = 100_000, n_targets: int = 10,
     energy = EnergyConfig(events=GridEventConfig(
         outages=((1, T_ep // 3, T_ep // 24),),
         shocks=((-1, T_ep // 2, T_ep // 12, 1.6),)))
-    # non-trivial fault plan: the throughput floor and RSS ceiling must
-    # hold with the signal plane degraded (the observed (T, R) feed and
-    # the (T,) gap vector are the only extra arrays — nothing (T, N))
+    # non-trivial fault plan: the observed (T, R) feed and the (T,) gap
+    # vector are the only extra arrays — nothing (T, N)
     flt = FaultPlan(
         carbon=CarbonFeedFaults(dropout_prob=0.2,
                                 blackouts=((-1, T_ep // 3, T_ep // 12),)),
@@ -728,37 +695,81 @@ def jax_sweep_scale(n_traces: int = 100_000, n_targets: int = 10,
         migration=MigrationFaults(fail_prob=0.2, backoff_cap=8),
         degrade=DegradeConfig(mode="ladder", ttl_epochs=3),
         seed=11)
+    return SweepSpec(
+        policies={"carbon_containers":
+                  lambda: CarbonContainerPolicy(variant="energy")},
+        family=fam, traces=demand,
+        targets=list(np.linspace(20.0, 80.0, n_targets)),
+        sim=SimConfig(target_rate=0.0), backend=backend, placement=eng,
+        traffic=traffic, elasticity=elastic, energy=energy, faults=flt)
 
-    def _sweep():
-        return sweep_population(policies, fam, demand, None, targets, cfg,
-                                backend="jax", placement=eng,
-                                traffic=traffic, elasticity=elastic,
-                                energy=energy, faults=flt)
 
-    t0 = time.perf_counter()
-    rows_w = _sweep()
-    warmup_s = time.perf_counter() - t0
-    t0 = time.perf_counter()
-    rows_jax = _sweep()
-    steady_s = time.perf_counter() - t0
-
-    # invariant-check plan, recomputed the way the sweep built it:
-    # grid shocks applied to the TRUE feed first (physical), then the
-    # degrade ladder on top — the planner only ever saw the observed
-    # signal, and threads the same seeded migration-failure mask
-    import copy as _copy
+def planner_view(spec):
+    """The placement engine as the sweep's planner sees it: grid shocks
+    applied to the TRUE feed first (physical), then the degrade ladder
+    on top — the planner only ever sees the observed signal. Planning
+    on it (with the spec's fault plan) reproduces the sweep's region
+    plan, for invariant and parity checks."""
+    import copy
 
     from repro.energy.supply import event_matrices
     from repro.robustness.degrade import observe_intensity
-    shock_mult, _ = event_matrices(energy.events, T_ep, eng.n_regions)
-    true_reg = eng._region_matrix(T_ep) * shock_mult
-    eng_chk = _copy.copy(eng)
-    eng_chk.regions = observe_intensity(true_reg, flt,
-                                        eng.interval_s).observed
-    plan = plan_jax(eng_chk, demand, state_gb=cfg.state_gb, faults=flt)
+    eng = spec.placement
+    T = np.asarray(spec.traces).shape[0]
+    shock_mult, _ = event_matrices(spec.energy.events, T, eng.n_regions)
+    view = copy.copy(eng)
+    view.regions = observe_intensity(eng._region_matrix(T) * shock_mult,
+                                     spec.faults, eng.interval_s).observed
+    return view
+
+
+def jax_sweep_scale(n_traces: int = 100_000, n_targets: int = 10,
+                    days: int = 1):
+    """The N=1M placed fleet sweep (`fleet_1m_spec`) through the full
+    jax path — vectorized trace generation, the capacity-planned region
+    schedule (`plan_jax`), and the memory-lean indexed-carbon fleet scan
+    (compact demand + in-step target tiling; no (T, N) array on host or
+    device) — with every layer on: the 1M-user traffic layer modulates
+    every container's demand, the virtual energy supply runs the host
+    supply ledger on the compact fleet (cap_frac applied on host, carbon
+    billed at the delivered mix through the indexed (c_eff, codes)
+    layout), the per-container elasticity layer runs its own
+    compact-width scan (the (N·K,) marginal-allocation argsort per
+    epoch) whose served demand feeds the fleet scan, and the
+    signal-plane fault plan degrades every decision plane. The 4 GB RSS
+    ceiling holds with all of it on, and the energy invariants
+    (conservation, zero cap/SoC violations) gate alongside the
+    throughput floor.
+
+    Headline numbers: `container_epochs_per_s` = N * T / steady_s
+    (steady state: second sweep call, jit cache warm), `warmup_s`
+    (first call, includes compile AND the placement plan),
+    `over_capacity_epochs` (the plan is recomputed once outside the
+    timed region for the invariant check — plans are deterministic, so
+    it is the same plan the sweep used). NumPy comparison is deliberately
+    absent: the fleet backend needs the ~2.3 GB tiled matrices and tens
+    of minutes at this N — parity is pinned at 50k by
+    tests/test_placement_scale.py instead.
+    """
+    from repro.cluster.placement_jax import plan_jax
+
+    t0 = time.perf_counter()
+    spec = fleet_1m_spec(n_traces, n_targets, days)
+    gen_s = time.perf_counter() - t0
+
+    t0 = time.perf_counter()
+    rows_w = spec.run().rows
+    warmup_s = time.perf_counter() - t0
+    t0 = time.perf_counter()
+    rows_jax = spec.run().rows
+    steady_s = time.perf_counter() - t0
+
+    plan = plan_jax(planner_view(spec), spec.traces,
+                    state_gb=spec.sim.state_gb, faults=spec.faults)
+    cap = spec.placement.config.capacity
     occ = plan.occupancy()
     n_containers = n_traces * n_targets
-    T = demand.shape[0]
+    T = spec.traces.shape[0]
     rows = [{"backend": "jax", "wall_s": steady_s,
              "n_containers": n_containers, "n_epochs": T,
              **{k: r[k] for k in ("policy", "target", "carbon_rate_mean",
@@ -776,7 +787,7 @@ def jax_sweep_scale(n_traces: int = 100_000, n_targets: int = 10,
         "placement_migrations": int(plan.migrations.sum()),
         "over_capacity_epochs": int((occ > cap).sum()),
         "rows_match_warmup": rows_jax == rows_w,
-        "traffic_n_users": traffic.population.n_users,
+        "traffic_n_users": spec.traffic.population.n_users,
         "traffic_served": rows_jax[0]["traffic_served"],
         "traffic_violation_rate": rows_jax[0]["traffic_violation_rate"],
         "traffic_carbon_per_request_g":

@@ -39,12 +39,16 @@ def _peak_rss_mb() -> float:
         return rss / 1e6
     return rss / 1024.0
 
-def _ensure_xla_flags():
+def _setup_jax():
     """CPU-tuned XLA flags for the jax-backend entries (the shared
     helper appends them only when absent, so explicit user settings
-    win); must run before the first jax backend initialization."""
+    win; they must be set before the first jax backend initialization)
+    and the persistent compilation cache, so a warm run's ``warmup_s``
+    is mostly cache reads."""
+    from repro.compile_cache import enable_compile_cache
     from repro.core.fleet_jax import ensure_cpu_xla_flags
     ensure_cpu_xla_flags()
+    enable_compile_cache()
 
 
 def _rows_to_csv(name: str, rows: list):
@@ -61,7 +65,7 @@ def _rows_to_csv(name: str, rows: list):
 
 
 def main() -> None:
-    _ensure_xla_flags()
+    _setup_jax()
     args = {}
     argv = sys.argv[1:]
     for i in range(0, len(argv) - 1, 2):

@@ -11,6 +11,7 @@ import numpy as np
 
 from repro.carbon.intensity import TraceProvider
 from repro.cluster.slices import paper_family
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_arch
 from repro.core.container import ContainerState, PlantModel
 from repro.core.policy import CarbonContainerPolicy
@@ -20,6 +21,7 @@ from repro.serve.scheduler import CarbonAwareScheduler, poisson_arrivals
 
 
 def main():
+    enable_compile_cache()
     spec = get_arch("smollm-135m")
     engine = ServeEngine(get_model(spec.smoke)).load()
     # calibrate capacity: measured decode throughput = duty-1.0 capacity

@@ -16,6 +16,7 @@ import jax
 
 from repro.carbon.intensity import TraceProvider
 from repro.cluster.slices import SliceFamily, Slice
+from repro.compile_cache import enable_compile_cache
 from repro.config import CarbonConfig, OptimizerConfig, TrainConfig
 from repro.configs import get_arch
 from repro.core.carbon_aware_trainer import CarbonAwareTrainer
@@ -39,6 +40,7 @@ def demo_family(n_devices: int) -> tuple:
 
 
 def main():
+    enable_compile_cache()
     steps = 200
     if "--steps" in sys.argv:
         steps = int(sys.argv[sys.argv.index("--steps") + 1])

@@ -26,6 +26,7 @@ import sys
 import numpy as np
 
 from repro.carbon.traces import synth_trace
+from repro.compile_cache import enable_compile_cache
 from repro.core.elasticity import ElasticityConfig, simulate_elastic
 
 INTERVAL_S = 3600.0
@@ -39,6 +40,7 @@ def _arg(flag, default, cast):
 
 
 def main():
+    enable_compile_cache()
     n = _arg("--containers", 2000, int)
     days = _arg("--days", 10, int)
     frac = _arg("--budget-frac", 0.6, float)
@@ -105,17 +107,12 @@ def main():
             lambda: CarbonContainerPolicy(variant="energy")}
     print(f"\nplaced sweep with elasticity (64 traces, both backends):")
     for backend in ("fleet", "jax"):
-        try:
-            rows = SweepSpec(policies=pols, family=fam, traces=traces,
-                             targets=[40.0], sim=SimConfig(target_rate=0.0),
-                             backend=backend,
-                             placement=PlacementConfig(capacity=64,
-                                                       min_dwell=6),
-                             regions=provs, region_names=REGIONS,
-                             elasticity=ec).run()
-        except ImportError:
-            print(f"  {backend:>6}: jax unavailable, skipped")
-            continue
+        rows = SweepSpec(policies=pols, family=fam, traces=traces,
+                         targets=[40.0], sim=SimConfig(target_rate=0.0),
+                         backend=backend,
+                         placement=PlacementConfig(capacity=64, min_dwell=6),
+                         regions=provs, region_names=REGIONS,
+                         elasticity=ec).run()
         r = rows[0]
         print(f"  {backend:>6}: carbon_rate={r['carbon_rate_mean']:.2f} "
               f"served={r['elastic_served_frac']:.1%} "

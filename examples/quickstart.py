@@ -6,6 +6,7 @@ import tempfile
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.config import OptimizerConfig, TrainConfig
 from repro.configs import get_arch
 from repro.data.pipeline import markov_stream
@@ -16,6 +17,7 @@ from repro.train import loop as TL
 
 
 def main():
+    enable_compile_cache()
     # 1. pick an assigned architecture (reduced config for CPU)
     spec = get_arch("smollm-135m")
     model = get_model(spec.smoke)

@@ -18,6 +18,7 @@ import numpy as np
 from repro.carbon.intensity import TraceProvider
 from repro.cluster.placement import PlacementConfig, PlacementEngine
 from repro.cluster.slices import paper_family
+from repro.compile_cache import enable_compile_cache
 from repro.core.fleet import FleetSimulator
 from repro.core.policy import (CarbonAgnosticPolicy, CarbonContainerPolicy,
                                SuspendResumePolicy, VScaleOnlyPolicy)
@@ -239,6 +240,7 @@ def jax_sweep(n_containers: int = 10080, n_targets: int = 12,
 
 
 def main():
+    enable_compile_cache()
     n_jobs = _arg("--jobs", 20, int)
     backend = _arg("--backend", "fleet", str)
     if backend not in ("fleet", "scalar"):

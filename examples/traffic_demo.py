@@ -21,6 +21,7 @@ import numpy as np
 from repro.carbon.intensity import TraceProvider
 from repro.cluster.placement import PlacementConfig, PlacementEngine
 from repro.cluster.slices import paper_family
+from repro.compile_cache import enable_compile_cache
 from repro.core.policy import CarbonContainerPolicy
 from repro.core.simulator import SimConfig
 from repro.core.spec import SweepSpec
@@ -39,6 +40,7 @@ def _arg(flag, default, cast):
 
 
 def main():
+    enable_compile_cache()
     n_users = _arg("--users", 1_000_000, int)
     days = _arg("--days", 1, int)
     budget = _arg("--budget", None, float)

@@ -37,9 +37,13 @@ The Pallas kernel (``admission_impl="pallas"``,
 through a grid with per-region "wanters seen so far" counters in SMEM —
 rank becomes counter + in-block prefix count, and the whole round is
 one O(N R) pass with the argmax, ranking, admission, and strike fused
-in a single kernel. ``"auto"`` picks pallas on TPU/GPU and the XLA
-rendering on CPU, where pallas runs in interpret mode (correct and
-parity-tested, but built from the same XLA ops it is meant to replace).
+in a single kernel. It reads the epoch's net savings as int32
+preference ranks (`placement_pallas.preference_ranks`, computed once
+per epoch in f64), so its decisions equal the f64 argmax's. ``"auto"``
+picks pallas on a TPU, where Mosaic compiles it, and the XLA rendering
+everywhere else; off a TPU an explicit ``"pallas"`` runs in interpret
+mode (correct and parity-tested, but built from the same XLA ops it is
+meant to replace).
 
 The result is the same `PlacementPlan` dataclass; parity against the
 NumPy planner is pinned to 1e-6 (assignments equal epoch-by-epoch) by
@@ -59,23 +63,11 @@ import numpy as np
 
 from repro.cluster.placement import PlacementPlan
 
-try:
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-    from jax.experimental import enable_x64
-    HAS_JAX = True
-except ImportError:                                    # pragma: no cover
-    HAS_JAX = False
-    jax = jnp = lax = enable_x64 = None
+import jax
+import jax.numpy as jnp
+from jax import lax
 
 ADMISSION_IMPLS = ("auto", "xla", "pallas")
-
-
-def _require_jax():
-    if not HAS_JAX:
-        raise ImportError("plan_jax requires jax; install jax[cpu] or use "
-                          "PlacementEngine.plan")
 
 
 def _sel_region(c_row, idx, R: int):
@@ -91,7 +83,8 @@ def _admission_round_xla(net, assign, eligible, dst, struck, remaining,
                          rows_r):
     """One preference round, pure-XLA: associative-scan ranking with a
     `lax.cond` fast path for uncontended rounds. Same (dst, struck,
-    want_total) contract as `placement_pallas.admission_round`."""
+    want_total) outputs as `placement_pallas.admission_round`, which
+    reads the round-invariant `net` as integer preference ranks."""
     cols = rows_r[None, :]
     net_eff = jnp.where(((struck[:, None] >> cols) & 1) > 0, -jnp.inf, net)
     best = jnp.argmax(net_eff, axis=1).astype(jnp.int32)
@@ -117,7 +110,7 @@ def _admission_round_xla(net, assign, eligible, dst, struck, remaining,
     return dst, struck, counts
 
 
-@partial(jax.jit if HAS_JAX else lambda f, **kw: f,
+@partial(jax.jit,
          static_argnames=("R", "min_dwell", "has_cap", "base_b", "span_b",
                           "mult_b", "h_hr", "hk", "admission_impl",
                           "block_n", "interpret", "has_faults", "bb", "bc"))
@@ -173,6 +166,10 @@ def _plan_scan(cmat, demand, assign0, occ0, cap, cost0, mig_s,
             # accumulate in the bitmask, so admitted(r) ==
             # min(want_total[r], remaining[r]) closes the counters.
             remaining0 = cap - occ
+            if admission_impl == "pallas":
+                from repro.cluster.placement_pallas import (
+                    admission_round, preference_ranks)
+                pref = preference_ranks(net)         # round-invariant
 
             def round_cond(rst):
                 _, _, _, rnd, cont = rst
@@ -181,10 +178,8 @@ def _plan_scan(cmat, demand, assign0, occ0, cap, cost0, mig_s,
             def round_body(rst):
                 dst_r, struck_r, remaining_r, rnd, _ = rst
                 if admission_impl == "pallas":
-                    from repro.cluster.placement_pallas import \
-                        admission_round
                     dst_r, struck_r, want_tot = admission_round(
-                        net, assign, eligible, dst_r, struck_r,
+                        pref, assign, eligible, dst_r, struck_r,
                         remaining_r, block_n=block_n, interpret=interpret)
                 else:
                     dst_r, struck_r, want_tot = _admission_round_xla(
@@ -272,6 +267,52 @@ def _trivial_plan(engine, cmat, assign0, has_faults=False) -> PlacementPlan:
         else None)
 
 
+def _prepare(engine, demand, state_gb, initial, admission_impl, block_n,
+             faults):
+    """Host inputs and static keywords of `_plan_scan` for one plan, or
+    ``(None, prep)`` for a shape where nothing can ever move."""
+    if admission_impl not in ADMISSION_IMPLS:
+        raise ValueError(f"admission_impl must be one of {ADMISSION_IMPLS}, "
+                         f"got {admission_impl!r}")
+    from repro.cluster.placement_pallas import default_interpret
+    from repro.robustness.faults import migration_failure_mask
+    prep = engine._prep(demand, state_gb, initial)
+    demand, cmat, cap, assign0, mig_s, cost0 = prep
+    T, N = demand.shape
+    R = engine.n_regions
+    fail_mat = migration_failure_mask(faults, T, N)
+    if N == 0 or R == 1 or T == 0:
+        # nothing can ever move: N=0 has no containers, R=1 has no
+        # destination (argmax == current region always), T=0 no epochs —
+        # skip tracing/compiling the round loop entirely
+        return None, (cmat, assign0, fail_mat is not None)
+    if admission_impl == "auto":
+        admission_impl = "xla" if default_interpret() else "pallas"
+    t = engine.tables
+    b = t.baseline_idx
+    base_b = float(t.base_w[b])
+    cfg = engine.config
+    has_cap = cap is not None
+    occ_host = (np.bincount(assign0, minlength=R).astype(np.int32)
+                if has_cap else np.zeros(R, dtype=np.int32))
+    cap_host = (cap.astype(np.int32) if has_cap
+                else np.zeros(R, dtype=np.int32))
+    kw = dict(R=R, min_dwell=int(cfg.min_dwell), has_cap=has_cap,
+              base_b=base_b, span_b=float(t.peak_w[b]) - base_b,
+              mult_b=float(t.multiple[b]),
+              h_hr=float(cfg.horizon_intervals * engine.interval_s / 3600.0),
+              hk=float(1.0 + cfg.hysteresis),
+              admission_impl=admission_impl, block_n=int(block_n),
+              interpret=default_interpret())
+    if fail_mat is not None:
+        kw.update(has_faults=True,
+                  bb=int(faults.migration.backoff_base),
+                  bc=int(faults.migration.backoff_cap))
+    args = (cmat, demand, assign0.astype(np.int32), occ_host, cap_host,
+            cost0, mig_s, fail_mat)
+    return (args, kw), (cmat, assign0, fail_mat is not None)
+
+
 def plan_jax(engine, demand, state_gb: float = 1.0, initial=None,
              admission_impl: str = "auto",
              block_n: int = 8192, faults=None) -> PlacementPlan:
@@ -280,10 +321,11 @@ def plan_jax(engine, demand, state_gb: float = 1.0, initial=None,
 
     `admission_impl` selects the capacity-admission kernel: `"xla"`
     (associative-scan ranking), `"pallas"` (streaming Pallas kernel,
-    interpret mode on CPU; `block_n` containers per grid step), or
-    `"auto"` — pallas on TPU/GPU, xla on CPU (see module docstring).
-    Both are pinned to the NumPy planner by the parity suite (and the
-    planner to the scalar reference at 1e-9).
+    compiled on a TPU and run in interpret mode elsewhere; `block_n`
+    containers per grid step), or `"auto"` — pallas on a TPU, xla
+    everywhere else (see module docstring). Both are pinned to the
+    NumPy planner by the parity suite (and the planner to the scalar
+    reference at 1e-9).
 
     `faults` (a `repro.robustness.FaultPlan`) injects the same seeded
     migration-failure mask as `PlacementEngine.plan` — failed attempts
@@ -291,64 +333,13 @@ def plan_jax(engine, demand, state_gb: float = 1.0, initial=None,
     backoff; parity with the NumPy planner is preserved because the
     mask derivation is shared.
     """
-    _require_jax()
-    if admission_impl not in ADMISSION_IMPLS:
-        raise ValueError(f"admission_impl must be one of {ADMISSION_IMPLS}, "
-                         f"got {admission_impl!r}")
-    from repro.robustness.faults import migration_failure_mask
-    demand, cmat, cap, assign0, mig_s, cost0 = engine._prep(
-        demand, state_gb, initial)
-    T, N = demand.shape
-    R = engine.n_regions
-    fail_mat = migration_failure_mask(faults, T, N)
-    if N == 0 or R == 1 or T == 0:
-        # nothing can ever move: N=0 has no containers, R=1 has no
-        # destination (argmax == current region always), T=0 no epochs —
-        # skip tracing/compiling the round loop entirely
-        return _trivial_plan(engine, cmat, assign0,
-                             has_faults=fail_mat is not None)
-    if admission_impl == "auto":
-        from repro.cluster.placement_pallas import default_interpret
-        admission_impl = "xla" if default_interpret() else "pallas"
-    t = engine.tables
-    b = t.baseline_idx
-    base_b = float(t.base_w[b])
-    span_b = float(t.peak_w[b]) - base_b
-    mult_b = float(t.multiple[b])
-    cfg = engine.config
-    h_hr = cfg.horizon_intervals * engine.interval_s / 3600.0
-    hk = 1.0 + cfg.hysteresis
-
-    has_cap = cap is not None
-    occ_host = (np.bincount(assign0, minlength=R).astype(np.int32)
-                if has_cap else np.zeros(R, dtype=np.int32))
-    cap_host = (cap.astype(np.int32) if has_cap
-                else np.zeros(R, dtype=np.int32))
-
-    interpret = True
-    if admission_impl == "pallas":
-        from repro.cluster.placement_pallas import default_interpret
-        interpret = default_interpret()
-
-    has_faults = fail_mat is not None
-    fault_kw = {}
-    if has_faults:
-        fault_kw = dict(has_faults=True,
-                        bb=int(faults.migration.backoff_base),
-                        bc=int(faults.migration.backoff_cap))
-
-    with enable_x64():
-        carry, assign_mat = _plan_scan(
-            jnp.asarray(cmat), jnp.asarray(demand),
-            jnp.asarray(assign0.astype(np.int32)),
-            jnp.asarray(occ_host), jnp.asarray(cap_host),
-            jnp.asarray(cost0), jnp.asarray(mig_s),
-            jnp.asarray(fail_mat) if has_faults else None,
-            R=R, min_dwell=int(cfg.min_dwell), has_cap=has_cap,
-            base_b=base_b, span_b=span_b, mult_b=mult_b,
-            h_hr=float(h_hr), hk=float(hk),
-            admission_impl=admission_impl, block_n=int(block_n),
-            interpret=interpret, **fault_kw)
+    call, (cmat, assign0, has_faults) = _prepare(
+        engine, demand, state_gb, initial, admission_impl, block_n, faults)
+    if call is None:
+        return _trivial_plan(engine, cmat, assign0, has_faults=has_faults)
+    args, kw = call
+    with jax.enable_x64(True):
+        carry, assign_mat = _plan_scan(*args, **kw)
         carry = jax.device_get(carry)
         migrations, overhead_g, downtime_s = carry[2], carry[3], carry[4]
         failed_migrations = (carry[8].astype(np.int64) if has_faults
@@ -363,3 +354,18 @@ def plan_jax(engine, demand, state_gb: float = 1.0, initial=None,
                          region_names=engine.region_names,
                          initial=assign0.copy(),
                          failed_migrations=failed_migrations)
+
+
+def lower_plan(engine, demand, state_gb: float = 1.0, initial=None,
+               admission_impl: str = "auto", block_n: int = 8192,
+               faults=None):
+    """The `jax.stages.Lowered` computation `plan_jax` runs for these
+    inputs, for inspection (e.g. that a TPU plan holds the compiled
+    admission kernel, a ``tpu_custom_call``)."""
+    call, _ = _prepare(engine, demand, state_gb, initial, admission_impl,
+                       block_n, faults)
+    if call is None:
+        raise ValueError("this plan is trivial: nothing is compiled")
+    args, kw = call
+    with jax.enable_x64(True):
+        return _plan_scan.lower(*args, **kw)

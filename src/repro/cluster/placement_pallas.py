@@ -13,118 +13,161 @@ counters ride along the container axis.
 
 Kernel layout (``admission_round``):
 
-  - grid over container blocks, sequential (``dimension_semantics=
+  - containers are laid out lane-dense as (rows, 128) tiles, padded to
+    whole blocks of ``block_n`` containers (padding is never eligible);
+    the grid runs over blocks, sequentially (``dimension_semantics=
     ("arbitrary",)``) so scratch carries across blocks;
-  - per-region wanter counters in SMEM scratch — the only cross-block
-    state, (R,) int32;
-  - per block: recompute the round's argmax-preference from the epoch's
-    (B, R) net tile and the packed strike bitmask, rank each wanter as
-    ``seen[r] + in-block prefix count``, admit iff rank <=
-    ``remaining[r]`` (the round-start snapshot — identical to the NumPy
-    kernel, which decrements per region *after* each region's cumsum),
-    and strike denied choices into the bitmask;
+  - per-region wanter counters are scalars in SMEM scratch — the only
+    cross-block state; ``remaining`` comes in, and ``want_total`` goes
+    out, through SMEM as well, read and written one region at a time;
+  - per block: pick each container's best un-struck region from the
+    epoch's integer preference table, rank each wanter as ``seen[r] +
+    in-block prefix count``, admit iff rank <= ``remaining[r]`` (the
+    round-start snapshot — identical to the NumPy kernel, which
+    decrements per region *after* each region's cumsum), and strike
+    denied choices into the bitmask;
+  - the in-block prefix count is two small matmuls against constant
+    triangular 0/1 matrices (within a 128-lane row, then across rows).
+    Their operands are 0/1 or row totals <= 128, exact in bfloat16, and
+    the f32 accumulation of at most ``block_n`` ones is exact, so the
+    ranks are exact integers;
   - the per-round carry is two packed int32 vectors (dst, struck) — no
     (N, R) tensor survives the round.
+
+The kernel sees integers only. The epoch's float64 net-saving table
+never changes within the round loop, so `preference_ranks` turns it
+once per epoch (in XLA, in f64) into an int32 rank per (region,
+container): 0 for the best region, ties to the lower region index
+(``np.argmax``'s first-max rule), and ``R`` for a region whose net
+saving is not positive. The best un-struck region is then the one with
+the smallest rank, and a container wants to move iff that rank is below
+``R`` — the same decision as the f64 argmax over un-struck regions, bit
+for bit, on every backend.
 
 The denial/early-exit bookkeeping needs only the per-region wanter
 totals: admitted(r) == min(want_total[r], remaining[r]) because
 admission takes exactly the first ``remaining[r]`` wanters. The final
 block publishes the SMEM counters as the (R,) ``want_total`` output.
 
-dtype is taken from ``net``: float64 under `enable_x64` on CPU (the
-parity-anchored interpret path), float32 on TPU/GPU where f64 is
-unavailable — the accelerator path trades the 1e-6 parity anchor for
-bit-exact *assignment* parity at f32-safe nets, like the rest of the
-kernels in `repro.kernels`. ``interpret=None`` resolves to interpret
-mode unless the default JAX backend is an accelerator, mirroring the
-flash_attention/ssd_scan CPU-fallback idiom.
+The kernel is compiled by Mosaic on a TPU. Elsewhere it runs in
+interpret mode (``interpret=None`` resolves by the default backend), so
+CPU tests run the same kernel body.
 """
 from __future__ import annotations
 
 import functools
 
-try:
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    HAS_PALLAS = True
-except ImportError:                                    # pragma: no cover
-    HAS_PALLAS = False
-    jax = jnp = pl = pltpu = None
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-DEFAULT_BLOCK = 8192     # containers per grid step (f64 net tile: 192KB at R=3)
+DEFAULT_BLOCK = 8192     # containers per grid step
+_LANES = 128
+_TILE = 8 * _LANES       # int32 (8, 128) tile: block granularity
 
 
-def _compiler_params(dimension_semantics):
-    """Version-portable pltpu compiler params (the class was renamed
-    across jax releases); shared with the model kernels."""
-    from repro.kernels.pallas_compat import compiler_params
-    return compiler_params(dimension_semantics)
+def preference_ranks(net):
+    """(N, R) float net-saving table -> (R, N) int32 preference ranks.
+
+    ``rank[r, i]`` is the position of region r in container i's order
+    of descending net saving (ties to the lower region index), or ``R``
+    where ``net[i, r] <= 0``. The smallest rank among a container's
+    un-struck regions names the region the f64 argmax would pick, and
+    that rank is below ``R`` iff its net saving is positive.
+    """
+    R = net.shape[1]
+    ranks = []
+    for r in range(R):
+        col = net[:, r]
+        rank = jnp.zeros(col.shape, jnp.int32)
+        for s in range(R):
+            if s == r:
+                continue
+            ahead = (net[:, s] >= col) if s < r else (net[:, s] > col)
+            rank = rank + ahead.astype(jnp.int32)
+        ranks.append(jnp.where(col > 0.0, rank, R))
+    return jnp.stack(ranks)
 
 
-def _round_kernel(net_ref, assign_ref, elig_ref, dst_ref, struck_ref,
+def _round_kernel(pref_ref, assign_ref, elig_ref, dst_ref, struck_ref,
                   remaining_ref, dst_out_ref, struck_out_ref, want_out_ref,
-                  seen_ref, *, R: int, B: int, N: int, NB: int):
+                  seen_ref, *, R: int, S: int, NB: int):
     b = pl.program_id(0)
 
     @pl.when(b == 0)
     def _init():
-        seen_ref[...] = jnp.zeros_like(seen_ref)
+        for r in range(R):
+            seen_ref[r] = 0
 
-    net = net_ref[...]                       # (B, R) epoch net, round-invariant
-    assign = assign_ref[...]                 # (B,)  current region
-    elig = elig_ref[...] > 0                 # (B,)  dwell >= min_dwell
-    dst = dst_ref[...]                       # (B,)  -1 = still unplaced
-    struck = struck_ref[...]                 # (B,)  denied-region bitmask
-    remaining = remaining_ref[...]           # (R,)  round-start free slots
+    assign = assign_ref[...]                 # (S, 128) current region
+    elig = elig_ref[...] > 0                 # dwell >= min_dwell
+    dst = dst_ref[...]                       # -1 = still unplaced
+    struck = struck_ref[...]                 # denied-region bitmask
 
-    rows = jax.lax.broadcasted_iota(jnp.int32, (B, R), 0)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (B, R), 1)
-    valid = (b * B + rows[:, 0]) < N         # mask the ragged last block
-
-    # argmax preference over un-struck regions; strict > keeps the first
-    # max on ties, matching np.argmax (R is small and static)
-    neg = jnp.asarray(-jnp.inf, net.dtype)
-    net_eff = jnp.where(((struck[:, None] >> cols) & 1) > 0, neg, net)
+    # best un-struck region = smallest preference rank (ranks of
+    # regions with a positive net are distinct; struck ones rank R)
     best = jnp.zeros(assign.shape, jnp.int32)
-    net_best = net_eff[:, 0]
-    for r in range(1, R):
-        m = net_eff[:, r] > net_best
+    best_rank = jnp.full(assign.shape, R, jnp.int32)
+    for r in range(R):
+        rank = jnp.where(((struck >> r) & 1) > 0, R, pref_ref[r])
+        m = rank < best_rank
         best = jnp.where(m, r, best)
-        net_best = jnp.where(m, net_eff[:, r], net_best)
+        best_rank = jnp.where(m, rank, best_rank)
+    want = elig & (dst < 0) & (best_rank < R) & (best != assign)
 
-    want = valid & elig & (dst < 0) & (net_best > 0.0) & (best != assign)
-    onehot = want[:, None] & (best[:, None] == cols)
-    # ranked admission: global inclusive rank = carried wanter count +
-    # in-block prefix count; the first `remaining[r]` wanters win
-    prefix = jnp.cumsum(onehot.astype(jnp.int32), axis=0)
-    seen = seen_ref[...]
-    admit = onehot & (seen[None, :] + prefix <= remaining[None, :])
-    admitted = admit.any(axis=1)
-    dst_out_ref[...] = jnp.where(admitted, best, dst)
-    denied = want & ~admitted
+    # in-block inclusive prefix count in container order (row-major):
+    # within-row prefix (x @ upper) plus the totals of earlier rows
+    # (lower_strict @ x @ ones); see the module docstring for exactness
+    bf16 = jnp.bfloat16
+    li = jax.lax.broadcasted_iota(jnp.int32, (_LANES, _LANES), 0)
+    lj = jax.lax.broadcasted_iota(jnp.int32, (_LANES, _LANES), 1)
+    upper = jnp.where(li <= lj, 1.0, 0.0).astype(bf16)
+    ones = jnp.ones((_LANES, _LANES), bf16)
+    si = jax.lax.broadcasted_iota(jnp.int32, (S, S), 0)
+    sj = jax.lax.broadcasted_iota(jnp.int32, (S, S), 1)
+    lower = jnp.where(si > sj, 1.0, 0.0).astype(bf16)
+
+    def dot(a, b_):
+        return jax.lax.dot(a, b_, preferred_element_type=jnp.float32)
+
+    dst_new = dst
+    for r in range(R):
+        wants_r = want & (best == r)
+        x = jnp.where(wants_r, 1.0, 0.0).astype(bf16)
+        row_tot = dot(x, ones).astype(bf16)
+        prefix = (dot(x, upper) + dot(lower, row_tot)).astype(jnp.int32)
+        seen = seen_ref[r]
+        dst_new = jnp.where(wants_r & (seen + prefix <= remaining_ref[r]),
+                            r, dst_new)
+        # the inclusive prefix at the block's last container is the
+        # block's wanter count
+        seen_ref[r] = seen + prefix[S - 1, _LANES - 1]
+    dst_out_ref[...] = dst_new
+    denied = want & (dst_new < 0)
     struck_out_ref[...] = jnp.where(denied, struck | (1 << best), struck)
-    seen_ref[...] = seen + prefix[-1]
 
     @pl.when(b == NB - 1)
     def _publish():
-        want_out_ref[...] = seen_ref[...]
+        for r in range(R):
+            want_out_ref[r] = seen_ref[r]
 
 
 def default_interpret() -> bool:
-    """Interpret (CPU-fallback) mode unless running on an accelerator."""
-    return jax.default_backend() not in ("tpu", "gpu", "cuda", "rocm")
+    """Interpret mode everywhere but a TPU (the kernel is Mosaic-only)."""
+    return jax.default_backend() != "tpu"
 
 
-def admission_round(net, assign, eligible, dst, struck, remaining, *,
+def admission_round(pref, assign, eligible, dst, struck, remaining, *,
                     block_n: int = DEFAULT_BLOCK, interpret=None):
     """One capacity-admission preference round as a single streaming pass.
 
-    Inputs: ``net`` (N, R) epoch net-saving table; ``assign``/(N,) i32
-    current regions; ``eligible`` (N,) i32/bool dwell gate; ``dst`` (N,)
-    i32 round carry (-1 = unplaced); ``struck`` (N,) i32 denied-region
-    bitmask carry; ``remaining`` (R,) i32 round-start free slots.
+    Inputs: ``pref`` (R, N) i32 epoch preference ranks
+    (`preference_ranks`); ``assign`` (N,) i32 current regions;
+    ``eligible`` (N,) i32/bool dwell gate; ``dst`` (N,) i32 round carry
+    (-1 = unplaced); ``struck`` (N,) i32 denied-region bitmask carry;
+    ``remaining`` (R,) i32 round-start free slots. ``block_n`` is
+    rounded up to whole (8, 128) int32 tiles.
 
     Returns ``(dst', struck', want_total)`` with ``want_total`` (R,) i32
     the number of containers that requested each region this round —
@@ -132,35 +175,43 @@ def admission_round(net, assign, eligible, dst, struck, remaining, *,
     min(want_total, remaining)) and evaluate the NumPy kernel's
     early-exit rule without touching (N, R) state.
     """
-    N, R = net.shape
+    R, N = pref.shape
     if interpret is None:
         interpret = default_interpret()
-    B = min(block_n, max(N, 1))
-    NB = max(1, -(-N // B))
-    kernel = functools.partial(_round_kernel, R=R, B=B, N=N, NB=NB)
-    elig_i = eligible.astype(jnp.int32)
-    return pl.pallas_call(
-        kernel,
-        grid=(NB,),
-        in_specs=[
-            pl.BlockSpec((B, R), lambda b: (b, 0)),
-            pl.BlockSpec((B,), lambda b: (b,)),
-            pl.BlockSpec((B,), lambda b: (b,)),
-            pl.BlockSpec((B,), lambda b: (b,)),
-            pl.BlockSpec((B,), lambda b: (b,)),
-            pl.BlockSpec((R,), lambda b: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((B,), lambda b: (b,)),
-            pl.BlockSpec((B,), lambda b: (b,)),
-            pl.BlockSpec((R,), lambda b: (0,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((N,), jnp.int32),      # dst'
-            jax.ShapeDtypeStruct((N,), jnp.int32),      # struck'
-            jax.ShapeDtypeStruct((R,), jnp.int32),      # want_total
-        ],
-        scratch_shapes=[pltpu.SMEM((R,), jnp.int32)],
-        compiler_params=_compiler_params(("arbitrary",)),
-        interpret=interpret,
-    )(net, assign, elig_i, dst, struck, remaining)
+    n_tiles = max(1, -(-N // _TILE))
+    B = _TILE * min(max(1, -(-block_n // _TILE)), n_tiles)
+    NB = -(-N // B)
+    pad = NB * B - N
+    S = B // _LANES
+
+    def lay(v, fill):                        # (N,) -> (NB * S, 128)
+        return jnp.pad(v.astype(jnp.int32), (0, pad),
+                       constant_values=fill).reshape(-1, _LANES)
+
+    pref_t = jnp.pad(pref, ((0, 0), (0, pad)),
+                     constant_values=R).reshape(R, -1, _LANES)
+    vec = pl.BlockSpec((S, _LANES), lambda b: (b, 0))
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    kernel = functools.partial(_round_kernel, R=R, S=S, NB=NB)
+    args = (pref_t, lay(assign, 0), lay(eligible, 0), lay(dst, -1),
+            lay(struck, 0), remaining.astype(jnp.int32))
+    # trace the kernel and its index maps with 32-bit defaults: callers
+    # run under enable_x64, and Mosaic has no 64-bit types
+    with jax.enable_x64(False):
+        dst2, struck2, want = pl.pallas_call(
+            kernel,
+            grid=(NB,),
+            in_specs=[pl.BlockSpec((R, S, _LANES), lambda b: (0, b, 0)),
+                      vec, vec, vec, vec, smem],
+            out_specs=[vec, vec, smem],
+            out_shape=[
+                jax.ShapeDtypeStruct((NB * S, _LANES), jnp.int32),  # dst'
+                jax.ShapeDtypeStruct((NB * S, _LANES), jnp.int32),  # struck'
+                jax.ShapeDtypeStruct((R,), jnp.int32),         # want_total
+            ],
+            scratch_shapes=[pltpu.SMEM((R,), jnp.int32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",)),
+            interpret=interpret,
+        )(*args)
+    return dst2.reshape(-1)[:N], struck2.reshape(-1)[:N], want

@@ -26,21 +26,24 @@ from __future__ import annotations
 
 import numpy as np
 
-try:
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-    from jax.experimental import enable_x64
-    HAS_JAX = True
-except ImportError:                                    # pragma: no cover
-    jax = jnp = lax = enable_x64 = None
-    HAS_JAX = False
+import jax
+import jax.numpy as jnp
+from jax import lax
 
 from repro.carbon.forecast import forecast_series
 from repro.core.elasticity import (ElasticityConfig, ElasticResult,
                                    shaped_budget_series)
 
 _SCAN_CACHE: dict = {}
+
+
+def _cumsum(x):
+    """Prefix sum as an explicit associative scan: `jnp.cumsum` lowers
+    to a whole-length reduce_window, which the TPU compiler takes
+    minutes to build for (N·K,) f64 at fleet scale; the scan's log-depth
+    tree compiles in seconds. Either association differs from NumPy's
+    sequential cumsum only in the last bits."""
+    return lax.associative_scan(jnp.add, x)
 
 
 def _spec_key(cfg: ElasticityConfig, interval_s: float):
@@ -92,7 +95,7 @@ def _build_scan(cfg: ElasticityConfig, interval_s: float, n: int,
                  * dt / 3600.0 * chat[:, None] / 1000.0)
             mand = k_idx <= lo[:, None]
             opt = (k_idx > lo[:, None]) & (k_idx <= desired[:, None])
-            mand_g = jnp.cumsum(jnp.where(mand, g, 0.0).ravel())[-1]
+            mand_g = _cumsum(jnp.where(mand, g, 0.0).ravel())[-1]
             # zero-gram guard: free levels first, no overflow division
             freeg = g <= 0.0
             eff = w / jnp.where(freeg, 1.0, g)
@@ -100,7 +103,7 @@ def _build_scan(cfg: ElasticityConfig, interval_s: float, n: int,
                               jnp.inf).ravel()
             order = jnp.argsort(score)                 # stable by default
             gs = jnp.where(opt, g, 0.0).ravel()[order]
-            cum = jnp.cumsum(gs)
+            cum = _cumsum(gs)
             admit = opt.ravel()[order] & (mand_g + cum <= bud)
             counts = jnp.zeros(n, dtype=jnp.float64).at[
                 jnp.asarray(con_of)[order]].add(admit.astype(jnp.float64))
@@ -180,9 +183,6 @@ def simulate_elastic_jax(demand, carbon, cfg: ElasticityConfig,
     fleet backend forecasts the very same matrix host-side, so the two
     stay bit-identical (forecast-then-gather on both).
     """
-    if not HAS_JAX:
-        raise ImportError("simulate_elastic_jax requires jax; use "
-                          "repro.core.elasticity.simulate_elastic")
     demand = np.asarray(demand, dtype=np.float64)
     if demand.ndim != 2:
         raise ValueError(f"demand must be (T, N); got {demand.shape}")
@@ -237,7 +237,7 @@ def simulate_elastic_jax(demand, carbon, cfg: ElasticityConfig,
 
     key = (_spec_key(cfg, dt), T, n, R, bool(record))
     fn = _SCAN_CACHE.get(key)
-    with enable_x64():
+    with jax.enable_x64(True):
         if fn is None:
             fn = _build_scan(cfg, dt, n, R, record)
             _SCAN_CACHE[key] = fn

@@ -11,7 +11,7 @@ ports the decision kernels and the epoch loop to JAX:
   - the epoch loop becomes one `jax.lax.scan` over time with the whole
     fleet state as the carry, so a full run compiles to a single XLA
     computation with no per-step Python dispatch;
-  - everything runs float64 (`jax.experimental.enable_x64`, scoped so
+  - everything runs float64 (`jax.enable_x64`, scoped so
     the f32 model/kernel suites are untouched) and device-resident: one
     host->device push of the inputs, one device->host pull of the final
     state.
@@ -69,15 +69,9 @@ from repro.core.fleet import (FleetResult, _aggregate_sweep_rows,
 from repro.core.policy import K_MIGRATE, K_RESUME, K_STAY, K_SUSPEND
 from repro.core.simulator import SimConfig
 
-try:
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-    from jax.experimental import enable_x64
-    HAS_JAX = True
-except ImportError:                                    # pragma: no cover
-    HAS_JAX = False
-    jax = jnp = lax = enable_x64 = None
+import jax
+import jax.numpy as jnp
+from jax import lax
 
 # rows of the packed scan carry (see _fleet_scan): acc carries the four
 # raw f64 sums, dyni carries i32 state then interval counters
@@ -86,19 +80,11 @@ _I_SLICE, _I_MT, _I_DWELL, _I_MIGS, _I_SUS, _I_SUSCNT = range(6)
 _MIN_SHARD_COLS = 1024   # don't shard fleets smaller than this per device
 
 
-def _require_jax():
-    if not HAS_JAX:
-        raise ImportError("backend='jax' requires jax; install jax[cpu] "
-                          "or use backend='fleet'")
-
-
-# CPU-tuned XLA flags: the legacy CPU runtime sidesteps the thunk
-# executor's per-kernel dispatch overhead inside scans, and multiple
-# host devices let `FleetSimulatorJax.run` shard the container axis
-# across cores (shards double as cache blocks, so more shards than
-# cores still helps large fleets).
-_CPU_XLA_FLAGS = ("--xla_cpu_use_thunk_runtime=false",
-                  "--xla_force_host_platform_device_count=4")
+# CPU-tuned XLA flags: multiple host devices let `FleetSimulatorJax.run`
+# shard the container axis across cores (shards double as cache blocks,
+# so more shards than cores still helps large fleets). They only affect
+# the CPU platform; on an accelerator the real devices are used.
+_CPU_XLA_FLAGS = ("--xla_force_host_platform_device_count=4",)
 
 
 def ensure_cpu_xla_flags():
@@ -114,6 +100,16 @@ def ensure_cpu_xla_flags():
         if f.split("=")[0] not in flags:
             flags = (flags + " " + f).strip()
     os.environ["XLA_FLAGS"] = flags
+
+
+def shard_count(n_devices: int, N: int, n_rep: Optional[int] = None) -> int:
+    """How many devices `FleetSimulatorJax.run` splits an N-container
+    fleet over: at most one shard per device and per `_MIN_SHARD_COLS`
+    containers, and for an indexed (rep-tiled) run at most one per rep
+    block."""
+    if n_rep is None:
+        return max(1, min(n_devices, N // _MIN_SHARD_COLS))
+    return max(1, min(n_devices, n_rep, N // _MIN_SHARD_COLS or 1))
 
 
 class _TablesS(NamedTuple):
@@ -444,7 +440,7 @@ _DECIDERS = {"agnostic": _decide_agnostic, "suspend_resume": _decide_sr,
 # The scan: whole (N,) fleet state as the carry, one step per epoch
 # ---------------------------------------------------------------------------
 
-@partial(jax.jit if HAS_JAX else lambda f, **kw: f,
+@partial(jax.jit,
          static_argnames=("spec", "srs", "record", "tabs", "dt", "mig",
                           "cmode", "n_rep", "R", "traffic", "energy"))
 def _fleet_scan(demand, cmat, targets, eps, state_gb, req_mat=None,
@@ -813,7 +809,6 @@ class FleetSimulatorJax:
     def __init__(self, family: SliceFamily, interval_s: float = 300.0,
                  suspend_releases_slice: bool = True,
                  migration: Optional[MigrationCostModel] = None):
-        _require_jax()
         self.family = family
         self.tables = family.tables()
         self.interval_s = float(interval_s)
@@ -950,15 +945,11 @@ class FleetSimulatorJax:
         # Indexed runs shard over rep blocks (the compact columns are
         # shared, so column shards would re-push them per device anyway).
         devices = jax.devices()
-        if indexed:
-            n_sh = max(1, min(len(devices), int(n_rep),
-                              N // _MIN_SHARD_COLS or 1))
-        else:
-            n_sh = max(1, min(len(devices), N // _MIN_SHARD_COLS))
+        n_sh = shard_count(len(devices), N, int(n_rep) if indexed else None)
         kw = dict(spec=spec, srs=self.suspend_releases_slice,
                   record=record, tabs=self._tabs, dt=dt,
                   mig=self._mig_spec())
-        with enable_x64():
+        with jax.enable_x64(True):
             outs = []
             for s in range(n_sh):
                 dev = devices[s]
@@ -1082,8 +1073,6 @@ def sweep_population_jax(policies: dict, family: SliceFamily, traces,
     power-telemetry gaps accrue `unmetered_g` — so the degraded
     signals are identical to the fleet backend's by construction.
     """
-    _require_jax()
-
     def _plan(eng, demand_plan, flt):
         from repro.cluster.placement_jax import plan_jax
         return plan_jax(eng, demand_plan, state_gb=cfg_base.state_gb,

@@ -20,15 +20,9 @@ import numpy as np
 
 from repro.energy.supply import SOC_SNAP_WH, EnergySpec, SupplyResult
 
-try:
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-    from jax.experimental import enable_x64
-    HAS_JAX = True
-except ImportError:                                    # pragma: no cover
-    HAS_JAX = False
-    jax = jnp = lax = enable_x64 = None
+import jax
+import jax.numpy as jnp
+from jax import lax
 
 
 def energy_step(spec: EnergySpec, soc, load, solar, grid_c, up):
@@ -68,9 +62,6 @@ def energy_step(spec: EnergySpec, soc, load, solar, grid_c, up):
 def simulate_supply_jax(load, solar, grid_c, grid_up,
                         spec: EnergySpec) -> SupplyResult:
     """Standalone scan of `energy_step` over all T epochs (float64)."""
-    if not HAS_JAX:
-        raise ImportError("simulate_supply_jax requires jax; use "
-                          "repro.energy.supply.simulate_supply")
     load = np.asarray(load, dtype=np.float64)
     solar = np.asarray(solar, dtype=np.float64)
     grid_c = np.asarray(grid_c, dtype=np.float64)
@@ -81,7 +72,7 @@ def simulate_supply_jax(load, solar, grid_c, grid_up,
         soc1, outs = energy_step(spec, soc, *x)
         return soc1, outs + (soc1,)
 
-    with enable_x64():
+    with jax.enable_x64(True):
         soc0 = jnp.full(R, spec.soc0_wh, dtype=jnp.float64)
         _, ys = jax.jit(lambda xs: lax.scan(step, soc0, xs))(
             (jnp.asarray(load), jnp.asarray(solar), jnp.asarray(grid_c),

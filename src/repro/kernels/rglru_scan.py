@@ -17,8 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import compiler_params
-
 from repro.kernels.ref import RGLRU_C
 
 
@@ -77,8 +75,8 @@ def rglru_scan_pallas(a: jax.Array, gx: jax.Array, h0: jax.Array, *,
             jax.ShapeDtypeStruct((B, W), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((1, bw), jnp.float32)],
-        compiler_params=compiler_params(
-            ("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, gx, h0)
     return y, h_last

@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import compiler_params
-
 
 def _kernel(x_ref, dt_ref, alog_ref, b_ref, c_ref, d_ref, y_ref, hout_ref,
             h_ref, *, nc: int, Q: int):
@@ -107,8 +105,8 @@ def ssd_pallas(x: jax.Array, dt: jax.Array, a_log: jax.Array,
             jax.ShapeDtypeStruct((B, H, P, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((bh, P, N), jnp.float32)],
-        compiler_params=compiler_params(
-            ("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, dt, a_log, b2, c2, d)
     return y, h_final

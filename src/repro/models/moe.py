@@ -22,7 +22,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.config import ModelConfig, SWIGLU, GEGLU
@@ -158,7 +158,7 @@ def _moe_mesh_path(cfg: ModelConfig, p: dict, x: jax.Array, mesh) -> tuple:
         inner, mesh=mesh,
         in_specs=(x_spec, P(None, None), wg_spec, wg_spec, wo_spec),
         out_specs=(x_spec, P(), P()),
-        check_rep=False,
+        check_vma=False,
     )(x, p["router"], p["wg"], p["wi"], p["wo"])
     return y, {"lb_loss": lb, "router_dropped": drop}
 

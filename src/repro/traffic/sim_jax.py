@@ -23,15 +23,9 @@ import numpy as np
 
 from repro.traffic.sim import TrafficConfig, TrafficResult
 
-try:
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-    from jax.experimental import enable_x64
-    HAS_JAX = True
-except ImportError:                                    # pragma: no cover
-    HAS_JAX = False
-    jax = jnp = lax = enable_x64 = None
+import jax
+import jax.numpy as jnp
+from jax import lax
 
 _BIG = 1e9
 
@@ -169,9 +163,6 @@ def traffic_step(spec: TrafficSpec, rep0, req_row, c_row):
 def simulate_traffic_jax(requests, region_intensity, cfg: TrafficConfig,
                          interval_s: float = 300.0) -> TrafficResult:
     """Standalone scan of `traffic_step` over all T epochs (float64)."""
-    if not HAS_JAX:
-        raise ImportError("simulate_traffic_jax requires jax; use "
-                          "repro.traffic.sim.simulate_traffic")
     requests = np.asarray(requests, dtype=np.float64)
     region_intensity = np.asarray(region_intensity, dtype=np.float64)
     spec = TrafficSpec.from_config(cfg, interval_s)
@@ -186,7 +177,7 @@ def simulate_traffic_jax(requests, region_intensity, cfg: TrafficConfig,
         rep1, outs = traffic_step(spec, rep, req_row, c_row)
         return rep1, outs + (rep1,)
 
-    with enable_x64():
+    with jax.enable_x64(True):
         rep0 = jnp.full(R, float(spec.min_rep), dtype=jnp.float64)
         _, ys = jax.jit(lambda xs: lax.scan(step, rep0, xs))(
             (jnp.asarray(requests), jnp.asarray(region_intensity)))

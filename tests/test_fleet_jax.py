@@ -181,7 +181,7 @@ def test_jax_rejects_negative_demand_and_bad_carbon():
                                    np.ones((3, 2)), 45.0)
 
 
-@pytest.mark.skipif(not fleet_jax.HAS_JAX or len(jax.devices()) < 2,
+@pytest.mark.skipif(len(jax.devices()) < 2,
                     reason="needs >= 2 XLA host devices "
                            "(XLA_FLAGS=--xla_force_host_platform_"
                            "device_count=2)")

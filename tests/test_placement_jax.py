@@ -111,26 +111,25 @@ def test_plan_jax_rejects_unknown_admission_impl():
 
 
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
-@pytest.mark.parametrize("block_n", [8192, 7],
+@pytest.mark.parametrize("n,block_n", [(18, 8192), (2500, 1024)],
                          ids=["one-block", "multi-block"])
-def test_admission_impl_parity(impl, block_n):
+def test_admission_impl_parity(impl, n, block_n):
     """The admission_impl dispatch: both backends must reproduce the
     NumPy planner exactly under tight capacity (every epoch runs denial
-    rounds). block_n=7 forces the pallas grid across ragged blocks so
-    the cross-block SMEM counter carry is exercised; the xla impl
-    ignores block_n (same dispatch surface either way)."""
-    if impl == "pallas":
-        pytest.importorskip("jax.experimental.pallas")
-    n = 18
+    rounds). The multi-block case runs the pallas grid over three
+    1024-container blocks, the last one padded, so the cross-block SMEM
+    counter carry is exercised; the xla impl ignores block_n (same
+    dispatch surface either way)."""
     provs, demand, state_gb = _inputs(n, seed=13)
+    cap = 7 * n // 18
     eng = PlacementEngine(
         paper_family(), provs, region_names=REGIONS,
-        config=PlacementConfig(capacity=7, min_dwell=4, hysteresis=0.10))
+        config=PlacementConfig(capacity=cap, min_dwell=4, hysteresis=0.10))
     p_np = eng.plan(demand, state_gb=state_gb)
     p_j = plan_jax(eng, demand, state_gb=state_gb,
                    admission_impl=impl, block_n=block_n)
     _assert_plans_equal(p_np, p_j, ctx=f"impl={impl} block={block_n}")
-    assert int((p_j.occupancy() > 7).sum()) == 0
+    assert int((p_j.occupancy() > cap).sum()) == 0
 
 
 def test_plan_jax_carbon_matrix_feeds_fleet():
@@ -143,3 +142,29 @@ def test_plan_jax_carbon_matrix_feeds_fleet():
     p_np = eng.plan(demand, state_gb=state_gb)
     p_j = plan_jax(eng, demand, state_gb=state_gb)
     assert np.array_equal(p_np.carbon_matrix(), p_j.carbon_matrix())
+
+
+def test_preference_ranks_match_f64_argmax():
+    """The admission kernel's integer view of the epoch's net table: for
+    every strike mask, the un-struck region with the smallest rank is
+    the f64 argmax over un-struck regions (first max on ties), and it
+    ranks below R iff that net saving is positive."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.cluster.placement_pallas import preference_ranks
+    R = 3
+    # few distinct values: ties and non-positive nets everywhere
+    net = np.random.default_rng(0).choice([-1.0, 0.0, 0.5, 2.0],
+                                          size=(4000, R))
+    with jax.enable_x64(True):
+        pref = np.asarray(preference_ranks(jnp.asarray(net)))
+    assert pref.shape == (R, 4000) and pref.dtype == np.int32
+    for mask in range(2 ** R):
+        struck = np.array([(mask >> r) & 1 for r in range(R)], dtype=bool)
+        net_eff = np.where(struck[None, :], -np.inf, net)
+        want = net_eff.max(axis=1) > 0.0
+        rank = np.where(struck[:, None], R, pref)
+        assert ((rank.min(axis=0) < R) == want).all(), mask
+        assert (rank.argmin(axis=0)[want]
+                == net_eff.argmax(axis=1)[want]).all(), mask

@@ -53,9 +53,12 @@ def test_init_params_pinned_values():
     p = init_params(tree, jax.random.PRNGKey(0))
     w = np.asarray(p["w"], dtype=np.float64)
     b = np.asarray(p["blk"]["b"], dtype=np.float64)
-    # pinned against crc32("w") / crc32("blk/b") fold_in salts
-    assert w.sum() == pytest.approx(0.029095228761434555, abs=1e-7)
-    assert w[0, 0] == pytest.approx(-0.02740298956632614, abs=1e-7)
-    assert b.sum() == pytest.approx(-0.012912587262690067, abs=1e-7)
+    # pinned against crc32("w") / crc32("blk/b") fold_in salts, with
+    # jax's default partitionable threefry (`jax_threefry_partitionable`
+    # is on by default since jax 0.5; the bits differ from the old
+    # default, so these pins are for that PRNG)
+    assert w.sum() == pytest.approx(0.13009770726785064, abs=1e-7)
+    assert w[0, 0] == pytest.approx(0.010057304054498672, abs=1e-7)
+    assert b.sum() == pytest.approx(0.07656742027029395, abs=1e-7)
     # per-path folding: distinct leaves draw distinct streams
     assert not np.allclose(w[:5].ravel()[: b.size], b)
