@@ -95,6 +95,9 @@ class PlacementPlan:
     region_names: tuple
     initial: np.ndarray              # (N,) pre-epoch-0 region index
     failed_migrations: Optional[np.ndarray] = None   # (N,) failed attempts
+    # (T,) capacity-admission preference rounds per epoch; kept by the JAX
+    # planner only, and None where no admission ran (uncapped, trivial)
+    admission_rounds: Optional[np.ndarray] = None
 
     @property
     def n_regions(self) -> int:

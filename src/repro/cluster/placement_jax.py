@@ -19,7 +19,8 @@ one `jax.lax.scan` over epochs:
     reverse-differentiable as-is — switch to a fixed-trip fori_loop
     first if you need gradients through admission;
   - one host->device push of (cmat, demand, cost0, mig_s), one pull of
-    the final carry + the (T, N) int32 assignment matrix.
+    the final carry, the (T, N) int32 assignment matrix and the (T,)
+    count of preference rounds each epoch ran.
 
 Why the ranked admission is the one hot path XLA handles badly
 --------------------------------------------------------------
@@ -61,6 +62,7 @@ from functools import partial
 
 import numpy as np
 
+from repro import obs
 from repro.cluster.placement import PlacementPlan
 
 import jax
@@ -127,7 +129,11 @@ def _plan_scan(cmat, demand, assign0, occ0, cap, cost0, mig_s,
     "xla" or "pallas" (`plan_jax` resolves "auto"). With
     `has_faults`, `fail_mat` is the shared (T, N) failed-migration
     mask and the carry gains the retry state (fail streak + earliest
-    retry epoch, capped exponential backoff `min(bb * 2**k, bc)`)."""
+    retry epoch, capped exponential backoff `min(bb * 2**k, bc)`).
+
+    Returns the final carry, the (T, N) int32 assignments and, with
+    `has_cap`, the (T,) int32 preference rounds each epoch ran (else
+    None)."""
     N = demand.shape[1]
     rows_r = jnp.arange(R, dtype=jnp.int32)
     T = demand.shape[0]
@@ -193,7 +199,7 @@ def _plan_scan(cmat, demand, assign0, occ0, cap, cost0, mig_s,
 
             dst0 = jnp.full(N, -1, dtype=jnp.int32)
             struck0 = jnp.zeros(N, dtype=jnp.int32)
-            dst, _, remaining, _, _ = lax.while_loop(
+            dst, _, remaining, rounds, _ = lax.while_loop(
                 round_cond, round_body,
                 (dst0, struck0, remaining0, jnp.int32(0), jnp.bool_(True)))
 
@@ -226,11 +232,12 @@ def _plan_scan(cmat, demand, assign0, occ0, cap, cost0, mig_s,
                    + dst_oh.sum(axis=0, dtype=jnp.int32))
         assign = jnp.where(moved, dst, assign)
         dwell = jnp.where(moved, 0, dwell + 1)
+        ys = (assign, rounds if has_cap else None)
         if has_faults:
             return (assign, dwell, migrations, overhead_g, downtime_s,
-                    occ, fail_cnt, retry_at, failed_migrations), assign
+                    occ, fail_cnt, retry_at, failed_migrations), ys
         return (assign, dwell, migrations, overhead_g, downtime_s,
-                occ), assign
+                occ), ys
 
     N_ = demand.shape[1]
     carry0 = (assign0,
@@ -246,8 +253,8 @@ def _plan_scan(cmat, demand, assign0, occ0, cap, cost0, mig_s,
         xs = (cmat, demand, fail_mat, t_vec)
     else:
         xs = (cmat, demand)
-    carry, assign_mat = lax.scan(step, carry0, xs)
-    return carry, assign_mat
+    carry, (assign_mat, rounds) = lax.scan(step, carry0, xs)
+    return carry, assign_mat, rounds
 
 
 def _trivial_plan(engine, cmat, assign0, has_faults=False) -> PlacementPlan:
@@ -333,27 +340,38 @@ def plan_jax(engine, demand, state_gb: float = 1.0, initial=None,
     backoff; parity with the NumPy planner is preserved because the
     mask derivation is shared.
     """
-    call, (cmat, assign0, has_faults) = _prepare(
-        engine, demand, state_gb, initial, admission_impl, block_n, faults)
-    if call is None:
-        return _trivial_plan(engine, cmat, assign0, has_faults=has_faults)
-    args, kw = call
-    with jax.enable_x64(True):
-        carry, assign_mat = _plan_scan(*args, **kw)
-        carry = jax.device_get(carry)
-        migrations, overhead_g, downtime_s = carry[2], carry[3], carry[4]
-        failed_migrations = (carry[8].astype(np.int64) if has_faults
-                             else None)
-        assign_mat = jax.device_get(assign_mat)
-
-    return PlacementPlan(assign=assign_mat.astype(np.int64),
-                         migrations=migrations.astype(np.int64),
-                         overhead_g=overhead_g,
-                         downtime_s=downtime_s,
-                         region_intensity=cmat,
-                         region_names=engine.region_names,
-                         initial=assign0.copy(),
-                         failed_migrations=failed_migrations)
+    with obs.span("plan"):
+        with obs.span("plan.prepare"):
+            call, (cmat, assign0, has_faults) = _prepare(
+                engine, demand, state_gb, initial, admission_impl, block_n,
+                faults)
+        if call is None:
+            return _trivial_plan(engine, cmat, assign0,
+                                 has_faults=has_faults)
+        args, kw = call
+        with jax.enable_x64(True):
+            with obs.span("plan.h2d"):
+                args = jax.block_until_ready(jax.device_put(args))
+                obs.count("h2d_bytes", obs.nbytes(args))
+            with obs.span("plan.wait"):
+                out = jax.block_until_ready(_plan_scan(*args, **kw))
+            with obs.span("plan.d2h"):
+                carry, assign_mat, rounds = jax.device_get(out)
+                obs.count("d2h_bytes", obs.nbytes((carry, assign_mat,
+                                                   rounds)))
+                if rounds is not None:
+                    obs.count("admission_rounds", rounds.sum())
+                return PlacementPlan(
+                    assign=assign_mat.astype(np.int64),
+                    migrations=carry[2].astype(np.int64),
+                    overhead_g=carry[3],
+                    downtime_s=carry[4],
+                    region_intensity=cmat,
+                    region_names=engine.region_names,
+                    initial=assign0.copy(),
+                    failed_migrations=(carry[8].astype(np.int64)
+                                       if has_faults else None),
+                    admission_rounds=rounds)
 
 
 def lower_plan(engine, demand, state_gb: float = 1.0, initial=None,

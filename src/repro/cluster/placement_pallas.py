@@ -49,9 +49,10 @@ totals: admitted(r) == min(want_total[r], remaining[r]) because
 admission takes exactly the first ``remaining[r]`` wanters. The final
 block publishes the SMEM counters as the (R,) ``want_total`` output.
 
-The kernel is compiled by Mosaic on a TPU. Elsewhere it runs in
-interpret mode (``interpret=None`` resolves by the default backend), so
-CPU tests run the same kernel body.
+The kernel is compiled by Mosaic on a TPU, where each round is one
+custom call named ``admission_round`` in the compiled program and the
+profiler's trace. Elsewhere it runs in interpret mode (``interpret=None``
+resolves by the default backend), so CPU tests run the same kernel body.
 """
 from __future__ import annotations
 
@@ -213,5 +214,6 @@ def admission_round(pref, assign, eligible, dst, struck, remaining, *,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",)),
             interpret=interpret,
+            name="admission_round",
         )(*args)
     return dst2.reshape(-1)[:N], struck2.reshape(-1)[:N], want

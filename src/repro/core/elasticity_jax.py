@@ -129,13 +129,13 @@ def _build_scan(cfg: ElasticityConfig, interval_s: float, n: int,
         ys = (srv / dt, alloc.astype(jnp.int32)) if record else srv / dt
         return (alloc, backlog, scal), ys
 
-    def scan_fn(xs):
+    def _elastic_scan(xs):
         st0 = (jnp.full(n, float(cfg.min_level), dtype=jnp.float64),
                jnp.zeros(n, dtype=jnp.float64),
                jnp.zeros(4, dtype=jnp.float64))
         return lax.scan(step, st0, xs)
 
-    return jax.jit(scan_fn)
+    return jax.jit(_elastic_scan)
 
 
 def _budget_array(budget_series, cfg: ElasticityConfig, dt: float,

@@ -60,6 +60,7 @@ from typing import NamedTuple, Optional, Sequence
 
 import numpy as np
 
+from repro import obs
 from repro.cluster.migration import MigrationCostModel
 from repro.cluster.slices import SliceFamily
 from repro.core.fleet import (FleetResult, _aggregate_sweep_rows,
@@ -588,187 +589,196 @@ def _fleet_scan(demand, cmat, targets, eps, state_gb, req_mat=None,
                 x = x[:-2]
             if traffic is not None:
                 d, code, c_row, req = x
-                # route this epoch's requests by the carbon row, scale
-                # the replica fleets; the serving loads modulate demand
-                # (the router is a controller: it sees the observed feed)
-                rep1, t_outs = traffic_step(
-                    traffic, rep, req, obs_row if has_obs else c_row)
-                mod_row = t_outs[0]
-                mod = jnp.full(code.shape, mod_row[0], dtype=jnp.float64)
-                for r in range(1, R):
-                    mod = jnp.where(code == r, mod_row[r], mod)
-                d = d * mod
+                with jax.named_scope("traffic"):
+                    # route this epoch's requests by the carbon row, scale
+                    # the replica fleets; the serving loads modulate demand
+                    # (the router is a controller: it sees the observed feed)
+                    rep1, t_outs = traffic_step(
+                        traffic, rep, req, obs_row if has_obs else c_row)
+                    mod_row = t_outs[0]
+                    mod = jnp.full(code.shape, mod_row[0], dtype=jnp.float64)
+                    for r in range(1, R):
+                        mod = jnp.where(code == r, mod_row[r], mod)
+                    d = d * mod
             else:
                 d, code, c_row = x
             if energy is not None:
-                # virtual energy supply: the compact columns sum into
-                # the (R,) flexible-load row (linear in demand, see
-                # repro.energy.supply), one battery/solar/grid step
-                # advances the (R,) SoC carry, and the cap fraction +
-                # effective intensity come back through the same R-way
-                # selects as the carbon row
-                load_row = jnp.stack(
-                    [jnp.sum(jnp.where(code == r, d, 0.0))
-                     for r in range(R)]) * energy.load_coef
-                c_raw = c_row           # true grid row, pre-delivered-mix
-                soc1, e_outs = energy_step(energy, soc, load_row,
-                                           sol_row, c_row, up_row)
-                cap_row, c_row = e_outs[5], e_outs[6]
-                if has_obs:
-                    # the controller observes the delivered mix through
-                    # the degraded feed: scale the effective intensity
-                    # by the per-region observed/true grid ratio (same
-                    # floats as the fleet backend's ceff_obs_reg)
-                    raw_safe = jnp.where(c_raw > 0.0, c_raw, 1.0)
-                    obs_row = c_row * jnp.where(
-                        c_raw > 0.0, obs_row / raw_safe, 1.0)
-                capsel = jnp.full(code.shape, cap_row[0],
-                                  dtype=jnp.float64)
+                with jax.named_scope("energy"):
+                    # virtual energy supply: the compact columns sum into
+                    # the (R,) flexible-load row (linear in demand, see
+                    # repro.energy.supply), one battery/solar/grid step
+                    # advances the (R,) SoC carry, and the cap fraction +
+                    # effective intensity come back through the same R-way
+                    # selects as the carbon row
+                    load_row = jnp.stack(
+                        [jnp.sum(jnp.where(code == r, d, 0.0))
+                         for r in range(R)]) * energy.load_coef
+                    c_raw = c_row           # true grid row, pre-delivered-mix
+                    soc1, e_outs = energy_step(energy, soc, load_row,
+                                               sol_row, c_row, up_row)
+                    cap_row, c_row = e_outs[5], e_outs[6]
+                    if has_obs:
+                        # the controller observes the delivered mix through
+                        # the degraded feed: scale the effective intensity
+                        # by the per-region observed/true grid ratio (same
+                        # floats as the fleet backend's ceff_obs_reg)
+                        raw_safe = jnp.where(c_raw > 0.0, c_raw, 1.0)
+                        obs_row = c_row * jnp.where(
+                            c_raw > 0.0, obs_row / raw_safe, 1.0)
+                    capsel = jnp.full(code.shape, cap_row[0],
+                                      dtype=jnp.float64)
+                    for r in range(1, R):
+                        capsel = jnp.where(code == r, cap_row[r], capsel)
+                    d = d * capsel
+            with jax.named_scope("signal"):
+                # R-way select chain over the epoch's (R,) region row — the
+                # compact-width analogue of gathering region_mat[t, codes[t]]
+                c = jnp.full(code.shape, c_row[0], dtype=jnp.float64)
                 for r in range(1, R):
-                    capsel = jnp.where(code == r, cap_row[r], capsel)
-                d = d * capsel
-            # R-way select chain over the epoch's (R,) region row — the
-            # compact-width analogue of gathering region_mat[t, codes[t]]
-            c = jnp.full(code.shape, c_row[0], dtype=jnp.float64)
-            for r in range(1, R):
-                c = jnp.where(code == r, c_row[r], c)
-            if has_obs:
-                c_dec = jnp.full(code.shape, obs_row[0], dtype=jnp.float64)
-                for r in range(1, R):
-                    c_dec = jnp.where(code == r, obs_row[r], c_dec)
-            if n_rep > 1:
-                d = jnp.tile(d, n_rep)
-                c = jnp.tile(c, n_rep)
+                    c = jnp.where(code == r, c_row[r], c)
                 if has_obs:
-                    c_dec = jnp.tile(c_dec, n_rep)
+                    c_dec = jnp.full(code.shape, obs_row[0], dtype=jnp.float64)
+                    for r in range(1, R):
+                        c_dec = jnp.where(code == r, obs_row[r], c_dec)
+                if n_rep > 1:
+                    d = jnp.tile(d, n_rep)
+                    c = jnp.tile(c, n_rep)
+                    if has_obs:
+                        c_dec = jnp.tile(c_dec, n_rep)
         else:
             d, c = x
             if has_obs:
                 c_dec = obs_row
-        if not has_obs:
-            c_dec = c
-        if use_peak:
-            acc, dynf, dyni, win = st
-            peak = d
-            for k in range(_PEAK_WINDOW - 1):
-                peak = jnp.maximum(peak, win[k])
-            win1 = jnp.concatenate([win[1:], d[None, :]], axis=0)
-        else:
-            acc, dynf, dyni = st
-            peak = jnp.zeros((), dtype=jnp.float64)
-        # per-interval power budget (policy._budget_batch, elementwise
-        # in the epoch's carbon values — same floats as the hoisted
-        # (T, N) form)
-        if spec[0] == "agnostic":
-            budget = jnp.zeros((), dtype=jnp.float64)
-        elif suspend_r:
-            budget = sr_budget
-        else:
-            c_safe = jnp.where(c_dec <= 0.0, 1.0, c_dec)
-            budget = jnp.where(c_dec <= 0.0, jnp.inf,
-                               (1.0 - eps) * targets * 1000.0 / c_safe)
-        i0 = dyni[_I_SLICE]
-        mt0 = dyni[_I_MT]
-        dwell0 = dyni[_I_DWELL]
-        sus = dyni[_I_SUS] > 0
-        duty0 = dynf[0]
-        migr_s0 = dynf[1]
-        migm = migr_s0 > 0.0
+        with jax.named_scope("signal"):
+            if not has_obs:
+                c_dec = c
+            if use_peak:
+                acc, dynf, dyni, win = st
+                peak = d
+                for k in range(_PEAK_WINDOW - 1):
+                    peak = jnp.maximum(peak, win[k])
+                win1 = jnp.concatenate([win[1:], d[None, :]], axis=0)
+            else:
+                acc, dynf, dyni = st
+                peak = jnp.zeros((), dtype=jnp.float64)
+            # per-interval power budget (policy._budget_batch, elementwise
+            # in the epoch's carbon values — same floats as the hoisted
+            # (T, N) form)
+            if spec[0] == "agnostic":
+                budget = jnp.zeros((), dtype=jnp.float64)
+            elif suspend_r:
+                budget = sr_budget
+            else:
+                c_safe = jnp.where(c_dec <= 0.0, 1.0, c_dec)
+                budget = jnp.where(c_dec <= 0.0, jnp.inf,
+                                   (1.0 - eps) * targets * 1000.0 / c_safe)
+        with jax.named_scope("decide"):
+            i0 = dyni[_I_SLICE]
+            mt0 = dyni[_I_MT]
+            dwell0 = dyni[_I_DWELL]
+            sus = dyni[_I_SUS] > 0
+            duty0 = dynf[0]
+            migr_s0 = dynf[1]
+            migm = migr_s0 > 0.0
 
-        kind, dy, tg = decide(spec, tabs, i0, sus, dwell0, peak, d, c_dec,
-                              budget)
-        kind = jnp.where(migm, -1, kind)
-        dstc = jnp.where(kind == K_MIGRATE, tg, 0)
-        dstc_m = jnp.where(migm, mt0, 0)
-        di = _pack(kind, tg, dstc, dstc_m)
-        kind, tg, dstc, dstc_m = di[0], di[1], di[2], di[3]
+            kind, dy, tg = decide(spec, tabs, i0, sus, dwell0, peak, d, c_dec,
+                                  budget)
+            kind = jnp.where(migm, -1, kind)
+            dstc = jnp.where(kind == K_MIGRATE, tg, 0)
+            dstc_m = jnp.where(migm, mt0, 0)
+            di = _pack(kind, tg, dstc, dstc_m)
+            kind, tg, dstc, dstc_m = di[0], di[1], di[2], di[3]
 
-        m_sus = kind == K_SUSPEND
-        m_res = kind == K_RESUME
-        m_stay = kind == K_STAY
-        m_mig = kind == K_MIGRATE
+            m_sus = kind == K_SUSPEND
+            m_res = kind == K_RESUME
+            m_stay = kind == K_STAY
+            m_mig = kind == K_MIGRATE
 
-        base_i = _lutf(tabs.base_w, i0)
-        base_dm = _lutf(tabs.base_w, dstc_m)    # in-flight migration dst
-        base_dst = _lutf(tabs.base_w, dstc)     # newly decided dst
+            base_i = _lutf(tabs.base_w, i0)
+            base_dm = _lutf(tabs.base_w, dstc_m)    # in-flight migration dst
+            base_dst = _lutf(tabs.base_w, dstc)     # newly decided dst
 
-        # stop-and-copy time (MigrationCostModel, same term order incl.
-        # the zero-bandwidth fallback) + post-decision slice + duty
-        bw = jnp.maximum(_lutf(tabs.bw_gbps, i0), _lutf(tabs.bw_gbps, dstc))
-        bw = jnp.where(bw == 0.0, default_bw, bw)
-        mig_s = (sb + spg * state_gb) + (rb + rpg * state_gb)
-        mig_s = mig_s + (cpg + dpg) * state_gb
-        mig_s = mig_s + (state_gb / ratio) / bw
-        mig_s = mig_s + extra
-        duty1 = jnp.where(m_res | m_stay | m_mig, dy, duty0)
-        pf = _pack(mig_s, duty1, base_i)
-        mig_s, duty, base_i = pf[0], pf[1], pf[2]
-        has_t = m_res & (tg >= 0)
-        longm = m_mig & (mig_s >= dt)
-        subm = m_mig & ~longm
-        idx1 = jnp.where(subm | has_t, tg, i0)
+            # stop-and-copy time (MigrationCostModel, same term order incl.
+            # the zero-bandwidth fallback) + post-decision slice + duty
+            bw = jnp.maximum(_lutf(tabs.bw_gbps, i0),
+                             _lutf(tabs.bw_gbps, dstc))
+            bw = jnp.where(bw == 0.0, default_bw, bw)
+            mig_s = (sb + spg * state_gb) + (rb + rpg * state_gb)
+            mig_s = mig_s + (cpg + dpg) * state_gb
+            mig_s = mig_s + (state_gb / ratio) / bw
+            mig_s = mig_s + extra
+            duty1 = jnp.where(m_res | m_stay | m_mig, dy, duty0)
+            pf = _pack(mig_s, duty1, base_i)
+            mig_s, duty, base_i = pf[0], pf[1], pf[2]
+            has_t = m_res & (tg >= 0)
+            longm = m_mig & (mig_s >= dt)
+            subm = m_mig & ~longm
+            idx1 = jnp.where(subm | has_t, tg, i0)
 
-        # ---- plant step for running containers ----------------------
-        mult_c = _lutf(tabs.multiple, idx1)
-        base_c = _lutf(tabs.base_w, idx1)
-        peak_c = _lutf(tabs.peak_w, idx1)
-        cap = mult_c * duty                     # duty in [0,1]: clamp elided
-        srv = jnp.minimum(d, cap)
-        util = srv / mult_c
-        pw = base_c + (peak_c - base_c) * util
-        down = jnp.minimum(mig_s, dt) / dt
-        p_mig = base_i + base_dst
-        full = m_res | m_stay
-        power = jnp.where(migm, base_i + base_dm, 0.0)
-        if not srs:
-            power = jnp.where(m_sus, base_i, power)
-        power = jnp.where(longm, p_mig, power)
-        power = jnp.where(full, pw, power)
-        power = jnp.where(subm, down * p_mig + (1.0 - down) * pw, power)
-        served = jnp.where(full, srv, 0.0)
-        served = jnp.where(subm, (1.0 - down) * srv, served)
-        ps = _pack(power, served)
-        power, served = ps[0], ps[1]
+        with jax.named_scope("plant"):
+            # ---- plant step for running containers ----------------------
+            mult_c = _lutf(tabs.multiple, idx1)
+            base_c = _lutf(tabs.base_w, idx1)
+            peak_c = _lutf(tabs.peak_w, idx1)
+            cap = mult_c * duty             # duty in [0,1]: clamp elided
+            srv = jnp.minimum(d, cap)
+            util = srv / mult_c
+            pw = base_c + (peak_c - base_c) * util
+            down = jnp.minimum(mig_s, dt) / dt
+            p_mig = base_i + base_dst
+            full = m_res | m_stay
+            power = jnp.where(migm, base_i + base_dm, 0.0)
+            if not srs:
+                power = jnp.where(m_sus, base_i, power)
+            power = jnp.where(longm, p_mig, power)
+            power = jnp.where(full, pw, power)
+            power = jnp.where(subm, down * p_mig + (1.0 - down) * pw, power)
+            served = jnp.where(full, srv, 0.0)
+            served = jnp.where(subm, (1.0 - down) * srv, served)
+            ps = _pack(power, served)
+            power, served = ps[0], ps[1]
 
-        # ---- fused accounting (scalar _account, reassociated) --------
-        # accumulate raw per-step sums; the loop-invariant dt/3600/1000
-        # scalings apply once after the scan. Time-on-slice and
-        # suspended time are interval *counters* (i32) scaled by dt at
-        # the end. Both reassociations shift results by ~1e-13 relative
-        # — far inside the backend's 1e-6 parity budget.
-        suspended1 = jnp.where(m_sus, True, sus)
-        suspended1 = jnp.where(m_res, False, suspended1)
-        tos_col = jnp.where(suspended1, S, idx1)
-        rows = [power * c,                              # -> emissions_g
-                power,                                  # -> energy_wh
-                served,                                 # -> work_done
-                jnp.maximum(0.0, d - served)]           # -> throttled
-        if traffic is not None or energy is not None:
-            rows.append(d)                              # -> work_demanded
-        if has_gap:
-            # telemetry outage: emissions happen but the meter is blind
-            rows.append(rows[0] * g)                    # -> unmetered_g
-        contribs = jnp.stack(rows)
-        acc1 = acc + contribs
+        with jax.named_scope("account"):
+            # ---- fused accounting (scalar _account, reassociated) --------
+            # accumulate raw per-step sums; the loop-invariant dt/3600/1000
+            # scalings apply once after the scan. Time-on-slice and
+            # suspended time are interval *counters* (i32) scaled by dt at
+            # the end. Both reassociations shift results by ~1e-13 relative
+            # — far inside the backend's 1e-6 parity budget.
+            suspended1 = jnp.where(m_sus, True, sus)
+            suspended1 = jnp.where(m_res, False, suspended1)
+            tos_col = jnp.where(suspended1, S, idx1)
+            rows = [power * c,                              # -> emissions_g
+                    power,                                  # -> energy_wh
+                    served,                                 # -> work_done
+                    jnp.maximum(0.0, d - served)]           # -> throttled
+            if traffic is not None or energy is not None:
+                rows.append(d)                              # -> work_demanded
+            if has_gap:
+                # telemetry outage: emissions happen but the meter is blind
+                rows.append(rows[0] * g)                    # -> unmetered_g
+            contribs = jnp.stack(rows)
+            acc1 = acc + contribs
 
-        # ---- migration progress + dwell (after accounting) ----------
-        migr1 = jnp.where(longm, mig_s - dt, migr_s0)
-        migr2 = jnp.where(migm, migr1 - dt, migr1)
-        done = migm & (migr2 <= 0.0)
-        slice2 = jnp.where(done, mt0, idx1)
-        mt1 = jnp.where(longm, tg, mt0)
-        mt2 = jnp.where(done, -1, mt1)
-        dwell1 = jnp.where(subm, 0, dwell0)
-        dwell1 = jnp.where(done, 0, dwell1)
-        dwell2 = dwell1 + ((kind >= 0) & (kind != K_MIGRATE))
-        migs2 = dyni[_I_MIGS] + m_mig
-        dynf1 = jnp.stack([duty, migr2])
-        dyni1 = jnp.concatenate(
-            [jnp.stack([slice2, mt2, dwell2, migs2,
-                        suspended1.astype(jnp.int32),
-                        dyni[_I_SUSCNT] + m_sus]),       # suspended count
-             dyni[_I_SUSCNT + 1:]
-             + (tos_col[None, :] == tos_cols[:, None])])
+        with jax.named_scope("migrate"):
+            # ---- migration progress + dwell (after accounting) ----------
+            migr1 = jnp.where(longm, mig_s - dt, migr_s0)
+            migr2 = jnp.where(migm, migr1 - dt, migr1)
+            done = migm & (migr2 <= 0.0)
+            slice2 = jnp.where(done, mt0, idx1)
+            mt1 = jnp.where(longm, tg, mt0)
+            mt2 = jnp.where(done, -1, mt1)
+            dwell1 = jnp.where(subm, 0, dwell0)
+            dwell1 = jnp.where(done, 0, dwell1)
+            dwell2 = dwell1 + ((kind >= 0) & (kind != K_MIGRATE))
+            migs2 = dyni[_I_MIGS] + m_mig
+            dynf1 = jnp.stack([duty, migr2])
+            dyni1 = jnp.concatenate(
+                [jnp.stack([slice2, mt2, dwell2, migs2,
+                            suspended1.astype(jnp.int32),
+                            dyni[_I_SUSCNT] + m_sus]),       # suspended count
+                 dyni[_I_SUSCNT + 1:]
+                 + (tos_col[None, :] == tos_cols[:, None])])
         ys = (power, served) if record else None
         st1 = ((acc1, dynf1, dyni1, win1) if use_peak
                else (acc1, dynf1, dyni1))
@@ -856,191 +866,185 @@ class FleetSimulatorJax:
         carries `unmetered_g`, the emissions accrued while the meter
         was blind.
         """
-        spec = _policy_spec(policy)
-        t = self.tables
-        dt = self.interval_s
-        indexed = isinstance(carbon, tuple)
-        if traffic is not None and not indexed:
-            raise ValueError("traffic fold requires indexed carbon "
-                             "(region_mat, codes)")
-        if energy is not None and not indexed:
-            raise ValueError("energy fold requires indexed carbon "
-                             "(region_mat, codes)")
-        if indexed:
-            region_mat, codes = carbon
-            demand = np.asarray(demand, dtype=np.float64)
-            if demand.ndim != 2:
-                raise ValueError("indexed-carbon run needs (T, n_cols) "
-                                 "demand")
-            if demand_scale is not None and np.any(
-                    np.asarray(demand_scale) != 1.0):
-                demand = demand * demand_scale
-            if demand.size and demand.min() < 0.0:
-                raise ValueError("fleet demand must be non-negative")
-            T, n_cols = demand.shape
-            N = n_cols * int(n_rep)
-            region_mat = np.asarray(region_mat, dtype=np.float64)
-            codes = np.asarray(codes, dtype=np.int32)
-            if region_mat.ndim != 2 or region_mat.shape[0] != T:
-                raise ValueError(f"region matrix shape {region_mat.shape}"
-                                 f" does not match demand (T={T})")
-            if codes.shape != (T, n_cols):
-                raise ValueError(f"region codes shape {codes.shape} does "
-                                 f"not match demand {(T, n_cols)}")
-            R = region_mat.shape[1]
-            t_spec = req_mat = None
-            if traffic is not None:
-                t_spec, req_mat = traffic
-                req_mat = np.asarray(req_mat, dtype=np.float64)
-                if req_mat.shape != (T, R):
-                    raise ValueError(f"traffic request tensor shape "
-                                     f"{req_mat.shape}; expected {(T, R)}")
-            e_spec = solar_mat = up_mat = None
-            if energy is not None:
-                e_spec, solar_mat, up_mat = energy
-                solar_mat = np.asarray(solar_mat, dtype=np.float64)
-                up_mat = np.asarray(up_mat, dtype=np.float64)
-                if solar_mat.shape != (T, R) or up_mat.shape != (T, R):
-                    raise ValueError(
-                        f"energy solar/grid-up tensor shapes "
-                        f"{solar_mat.shape} / {up_mat.shape}; expected "
-                        f"{(T, R)}")
-            targets = np.broadcast_to(
-                np.asarray(targets, dtype=np.float64), (N,))
-            epsilon = np.broadcast_to(
-                np.asarray(epsilon, dtype=np.float64), (N,))
-            state_gb = np.broadcast_to(
-                np.asarray(state_gb, dtype=np.float64), (N,))
-        else:
-            if n_rep != 1:
-                raise ValueError("n_rep tiling requires indexed carbon")
-            (demand, cmat, targets, epsilon, state_gb, T, N) = \
-                _prepare_run_inputs(demand, carbon, targets, epsilon,
-                                    state_gb, demand_scale, self.interval_s)
-            R = 0
-        if carbon_obs is not None:
-            carbon_obs = np.asarray(carbon_obs, dtype=np.float64)
+        with obs.span("fleet.prepare"):
+            spec = _policy_spec(policy)
+            t = self.tables
+            dt = self.interval_s
+            indexed = isinstance(carbon, tuple)
+            if traffic is not None and not indexed:
+                raise ValueError("traffic fold requires indexed carbon "
+                                 "(region_mat, codes)")
+            if energy is not None and not indexed:
+                raise ValueError("energy fold requires indexed carbon "
+                                 "(region_mat, codes)")
             if indexed:
-                if carbon_obs.shape != (T, R):
-                    raise ValueError(f"observed carbon shape "
-                                     f"{carbon_obs.shape}; indexed runs "
-                                     f"need the (T, R) region form "
-                                     f"{(T, R)}")
-            elif carbon_obs.shape not in ((T,), (T, N)):
-                raise ValueError(f"observed carbon shape "
-                                 f"{carbon_obs.shape} does not match "
-                                 f"(T,)={T,} or (T, N)={(T, N)}")
-        if power_gap is not None:
-            power_gap = np.asarray(power_gap, dtype=np.float64)
-            if power_gap.shape != (T,):
-                raise ValueError(f"power-gap vector shape "
-                                 f"{power_gap.shape}; expected {(T,)}")
-
-        # container-parallel sharding: containers never interact, so the
-        # fleet splits into contiguous column shards dispatched to the
-        # host's XLA devices (jax dispatch is async — shards execute
-        # concurrently, one thread pool per device). Results concatenate
-        # bit-identically to the unsharded run. Multiple host devices
-        # come from XLA_FLAGS=--xla_force_host_platform_device_count=K.
-        # Indexed runs shard over rep blocks (the compact columns are
-        # shared, so column shards would re-push them per device anyway).
-        devices = jax.devices()
-        n_sh = shard_count(len(devices), N, int(n_rep) if indexed else None)
-        kw = dict(spec=spec, srs=self.suspend_releases_slice,
-                  record=record, tabs=self._tabs, dt=dt,
-                  mig=self._mig_spec())
-        with jax.enable_x64(True):
-            outs = []
-            for s in range(n_sh):
-                dev = devices[s]
+                region_mat, codes = carbon
+                demand = np.asarray(demand, dtype=np.float64)
+                if demand.ndim != 2:
+                    raise ValueError("indexed-carbon run needs (T, n_cols) "
+                                     "demand")
+                if demand_scale is not None and np.any(
+                        np.asarray(demand_scale) != 1.0):
+                    demand = demand * demand_scale
+                if demand.size and demand.min() < 0.0:
+                    raise ValueError("fleet demand must be non-negative")
+                T, n_cols = demand.shape
+                N = n_cols * int(n_rep)
+                region_mat = np.asarray(region_mat, dtype=np.float64)
+                codes = np.asarray(codes, dtype=np.int32)
+                if region_mat.ndim != 2 or region_mat.shape[0] != T:
+                    raise ValueError(f"region matrix shape {region_mat.shape}"
+                                     f" does not match demand (T={T})")
+                if codes.shape != (T, n_cols):
+                    raise ValueError(f"region codes shape {codes.shape} does "
+                                     f"not match demand {(T, n_cols)}")
+                R = region_mat.shape[1]
+                t_spec = req_mat = None
+                if traffic is not None:
+                    t_spec, req_mat = traffic
+                    req_mat = np.asarray(req_mat, dtype=np.float64)
+                    if req_mat.shape != (T, R):
+                        raise ValueError(f"traffic request tensor shape "
+                                         f"{req_mat.shape}; expected {(T, R)}")
+                e_spec = solar_mat = up_mat = None
+                if energy is not None:
+                    e_spec, solar_mat, up_mat = energy
+                    solar_mat = np.asarray(solar_mat, dtype=np.float64)
+                    up_mat = np.asarray(up_mat, dtype=np.float64)
+                    if solar_mat.shape != (T, R) or up_mat.shape != (T, R):
+                        raise ValueError(
+                            f"energy solar/grid-up tensor shapes "
+                            f"{solar_mat.shape} / {up_mat.shape}; expected "
+                            f"{(T, R)}")
+                targets = np.broadcast_to(
+                    np.asarray(targets, dtype=np.float64), (N,))
+                epsilon = np.broadcast_to(
+                    np.asarray(epsilon, dtype=np.float64), (N,))
+                state_gb = np.broadcast_to(
+                    np.asarray(state_gb, dtype=np.float64), (N,))
+            else:
+                if n_rep != 1:
+                    raise ValueError("n_rep tiling requires indexed carbon")
+                (demand, cmat, targets, epsilon, state_gb, T, N) = \
+                    _prepare_run_inputs(demand, carbon, targets, epsilon,
+                                        state_gb, demand_scale, self.interval_s)
+                R = 0
+            if carbon_obs is not None:
+                carbon_obs = np.asarray(carbon_obs, dtype=np.float64)
                 if indexed:
-                    lo_r = s * n_rep // n_sh
-                    hi_r = (s + 1) * n_rep // n_sh
-                    lo, hi = lo_r * n_cols, hi_r * n_cols
-                    cm = (jax.device_put(region_mat, dev),
-                          jax.device_put(codes, dev))
-                    dm = jax.device_put(demand, dev)
-                    rq = (jax.device_put(req_mat, dev)
-                          if traffic is not None else None)
-                    sm = (jax.device_put(solar_mat, dev)
-                          if energy is not None else None)
-                    um = (jax.device_put(up_mat, dev)
-                          if energy is not None else None)
-                    ob = (jax.device_put(carbon_obs, dev)
-                          if carbon_obs is not None else None)
-                    gp = (jax.device_put(power_gap, dev)
-                          if power_gap is not None else None)
-                    outs.append(_fleet_scan(
-                        dm, cm,
-                        jax.device_put(targets[lo:hi], dev),
-                        jax.device_put(epsilon[lo:hi], dev),
-                        jax.device_put(state_gb[lo:hi], dev), rq, sm, um,
-                        ob, gp,
-                        cmode="indexed", n_rep=hi_r - lo_r, R=R,
-                        traffic=t_spec, energy=e_spec, **kw))
-                else:
-                    lo = s * N // n_sh
-                    hi = (s + 1) * N // n_sh
-                    cm = cmat if cmat.ndim == 1 else cmat[:, lo:hi]
-                    ob = None
-                    if carbon_obs is not None:
-                        ob = (carbon_obs if carbon_obs.ndim == 1
-                              else carbon_obs[:, lo:hi])
-                        ob = jax.device_put(ob, dev)
-                    gp = (jax.device_put(power_gap, dev)
-                          if power_gap is not None else None)
-                    outs.append(_fleet_scan(
-                        jax.device_put(demand[:, lo:hi], dev),
-                        jax.device_put(cm, dev),
-                        jax.device_put(targets[lo:hi], dev),
-                        jax.device_put(epsilon[lo:hi], dev),
-                        jax.device_put(state_gb[lo:hi], dev),
-                        obs_mat=ob, gap_vec=gp, **kw))
-            acc = np.concatenate(
-                [jax.device_get(o[0][0]) for o in outs], axis=1)
-            dyni = np.concatenate(
-                [jax.device_get(o[0][2]) for o in outs], axis=1)
-            ys = None
-            if record:
-                ys = tuple(np.concatenate(
-                    [jax.device_get(o[1][k]) for o in outs], axis=1)
-                    for k in range(2))
+                    if carbon_obs.shape != (T, R):
+                        raise ValueError(f"observed carbon shape "
+                                         f"{carbon_obs.shape}; indexed runs "
+                                         f"need the (T, R) region form "
+                                         f"{(T, R)}")
+                elif carbon_obs.shape not in ((T,), (T, N)):
+                    raise ValueError(f"observed carbon shape "
+                                     f"{carbon_obs.shape} does not match "
+                                     f"(T,)={T,} or (T, N)={(T, N)}")
+            if power_gap is not None:
+                power_gap = np.asarray(power_gap, dtype=np.float64)
+                if power_gap.shape != (T,):
+                    raise ValueError(f"power-gap vector shape "
+                                     f"{power_gap.shape}; expected {(T,)}")
 
-        elapsed = float(np.cumsum(np.full(T, dt))[-1]) if T else 0.0
-        if traffic is not None or energy is not None:
-            # host demand is pre-modulation/pre-cap: the scan's fifth
-            # accumulator row carries the effective per-container sums
-            work_dem = acc[_ACC_ROWS] * dt
-        else:
-            work_dem = demand.sum(axis=0) * dt
-            if indexed and n_rep > 1:
-                work_dem = np.tile(work_dem, n_rep)
-        # loop-invariant scalings deferred out of the scan (see
-        # _fleet_scan's accounting note); term order mirrors _account
-        return FleetResult(
-            emissions_g=acc[0] / 1000.0 * dt / 3600.0,
-            energy_wh=acc[1] * dt / 3600.0,
-            work_done=acc[2] * dt,
-            work_demanded=work_dem,
-            throttled_integral=acc[3] * dt,
-            migrations=dyni[_I_MIGS].astype(np.int64),
-            suspended_s=dyni[_I_SUSCNT].astype(np.float64) * dt,
-            elapsed_s=np.full(N, elapsed),
-            time_on_slice_s=np.ascontiguousarray(
-                dyni[_I_SUSCNT + 1:].T.astype(np.float64)) * dt,
-            slice_names=t.names + ("suspended",),
-            baseline_cap=float(t.multiple[t.baseline_idx]),
-            power_series=ys[0] if record else None,
-            served_series=ys[1] if record else None,
-            unmetered_g=(acc[-1] / 1000.0 * dt / 3600.0
-                         if power_gap is not None else None),
-        )
+            # container-parallel sharding: containers never interact, so the
+            # fleet splits into contiguous column shards dispatched to the
+            # host's XLA devices (jax dispatch is async — shards execute
+            # concurrently, one thread pool per device). Results concatenate
+            # bit-identically to the unsharded run. Multiple host devices
+            # come from XLA_FLAGS=--xla_force_host_platform_device_count=K.
+            # Indexed runs shard over rep blocks (the compact columns are
+            # shared, so column shards would re-push them per device
+            # anyway).
+            devices = jax.devices()
+            n_sh = shard_count(len(devices), N,
+                               int(n_rep) if indexed else None)
+            kw = dict(spec=spec, srs=self.suspend_releases_slice,
+                      record=record, tabs=self._tabs, dt=dt,
+                      mig=self._mig_spec())
+        with jax.enable_x64(True):
+            with obs.span("fleet.h2d"):
+                shards, statics = [], []
+                for s in range(n_sh):
+                    if indexed:
+                        lo_r = s * n_rep // n_sh
+                        hi_r = (s + 1) * n_rep // n_sh
+                        lo, hi = lo_r * n_cols, hi_r * n_cols
+                        args = (demand, (region_mat, codes), targets[lo:hi],
+                                epsilon[lo:hi], state_gb[lo:hi], req_mat,
+                                solar_mat, up_mat, carbon_obs, power_gap)
+                        static = dict(cmode="indexed", n_rep=hi_r - lo_r,
+                                      R=R, traffic=t_spec, energy=e_spec)
+                    else:
+                        lo = s * N // n_sh
+                        hi = (s + 1) * N // n_sh
+                        ob = carbon_obs
+                        if carbon_obs is not None and carbon_obs.ndim == 2:
+                            ob = carbon_obs[:, lo:hi]
+                        args = (demand[:, lo:hi],
+                                cmat if cmat.ndim == 1 else cmat[:, lo:hi],
+                                targets[lo:hi], epsilon[lo:hi],
+                                state_gb[lo:hi], None, None, None, ob,
+                                power_gap)
+                        static = {}
+                    shards.append(jax.device_put(args, devices[s]))
+                    statics.append(static)
+                # every shard's push is in flight before the one wait
+                shards = jax.block_until_ready(shards)
+                obs.count("h2d_bytes", obs.nbytes(shards))
+            outs = [_fleet_scan(*args, **static, **kw)
+                    for args, static in zip(shards, statics)]
+            with obs.span("fleet.wait"):
+                jax.block_until_ready(outs)
+            with obs.span("fleet.d2h"):
+                got = jax.device_get(
+                    [(o[0][0], o[0][2], o[1] if record else None)
+                     for o in outs])
+                obs.count("d2h_bytes", obs.nbytes(got))
+                acc = np.concatenate([g[0] for g in got], axis=1)
+                dyni = np.concatenate([g[1] for g in got], axis=1)
+                ys = None
+                if record:
+                    ys = tuple(np.concatenate([g[2][k] for g in got], axis=1)
+                               for k in range(2))
+
+        with obs.span("fleet.result"):
+            elapsed = float(np.cumsum(np.full(T, dt))[-1]) if T else 0.0
+            if traffic is not None or energy is not None:
+                # host demand is pre-modulation/pre-cap: the scan's fifth
+                # accumulator row carries the effective per-container sums
+                work_dem = acc[_ACC_ROWS] * dt
+            else:
+                work_dem = demand.sum(axis=0) * dt
+                if indexed and n_rep > 1:
+                    work_dem = np.tile(work_dem, n_rep)
+            # loop-invariant scalings deferred out of the scan (see
+            # _fleet_scan's accounting note); term order mirrors _account
+            return FleetResult(
+                emissions_g=acc[0] / 1000.0 * dt / 3600.0,
+                energy_wh=acc[1] * dt / 3600.0,
+                work_done=acc[2] * dt,
+                work_demanded=work_dem,
+                throttled_integral=acc[3] * dt,
+                migrations=dyni[_I_MIGS].astype(np.int64),
+                suspended_s=dyni[_I_SUSCNT].astype(np.float64) * dt,
+                elapsed_s=np.full(N, elapsed),
+                time_on_slice_s=np.ascontiguousarray(
+                    dyni[_I_SUSCNT + 1:].T.astype(np.float64)) * dt,
+                slice_names=t.names + ("suspended",),
+                baseline_cap=float(t.multiple[t.baseline_idx]),
+                power_series=ys[0] if record else None,
+                served_series=ys[1] if record else None,
+                unmetered_g=(acc[-1] / 1000.0 * dt / 3600.0
+                             if power_gap is not None else None),
+            )
 
 
 # ---------------------------------------------------------------------------
 # Population sweep on the JAX path (backend="jax" in sweep_population)
 # ---------------------------------------------------------------------------
 
+@obs.sweep()
 def sweep_population_jax(policies: dict, family: SliceFamily, traces,
                          carbon, targets: Sequence[float],
                          cfg_base: SimConfig,
@@ -1079,27 +1083,28 @@ def sweep_population_jax(policies: dict, family: SliceFamily, traces,
                         admission_impl=admission_impl, faults=flt)
 
     compact = placement is not None
-    (demand_one, tgt_one, carbon, plan, n_tr, n_tg, grid_up, fault_ctx) = \
-        _prepare_sweep_inputs(traces, carbon, targets, cfg_base,
-                              demand_scale, placement, _plan,
-                              tile=not compact, energy=energy,
-                              faults=faults)
+    with obs.span("sweep.prepare"):
+        (demand_one, tgt_one, carbon, plan, n_tr, n_tg, grid_up,
+         fault_ctx) = _prepare_sweep_inputs(
+            traces, carbon, targets, cfg_base, demand_scale, placement,
+            _plan, tile=not compact, energy=energy, faults=faults)
     n_rep = 1
     carbon_obs = None
     gap_vec = fault_ctx.gap_vec if fault_ctx is not None else None
     if compact:
         if fault_ctx is None:
-            carbon = (plan.region_intensity, plan.assign.astype(np.int32))
+            carbon = (plan.region_intensity, plan.assign)
         else:
             # bill at the TRUE region intensities; the plan's own table
             # (region_intensity) IS the observed feed under faults and
             # becomes the scan's decision signal
-            carbon = (fault_ctx.true_reg, plan.assign.astype(np.int32))
+            carbon = (fault_ctx.true_reg, plan.assign)
             carbon_obs = plan.region_intensity
         n_rep = n_tg
     elif fault_ctx is not None:
-        obs = fault_ctx.obs_reg
-        carbon_obs = np.tile(obs, (1, n_tg)) if obs.ndim == 2 else obs
+        obs_reg = fault_ctx.obs_reg
+        carbon_obs = (np.tile(obs_reg, (1, n_tg)) if obs_reg.ndim == 2
+                      else obs_reg)
 
     traffic_summary = None
     run_traffic = None
@@ -1107,7 +1112,9 @@ def sweep_population_jax(policies: dict, family: SliceFamily, traces,
     T = demand_one.shape[0]
     if traffic is not None:
         from repro.traffic.sim_jax import TrafficSpec
-        arr, tres = _prepare_traffic(traffic, plan, T, cfg_base.interval_s)
+        with obs.span("sweep.traffic"):
+            arr, tres = _prepare_traffic(traffic, plan, T,
+                                         cfg_base.interval_s)
         traffic_summary = tres.summary()
         if elasticity is None:
             # the in-scan traffic_step fold drives the demand modulation
@@ -1145,10 +1152,11 @@ def sweep_population_jax(policies: dict, family: SliceFamily, traces,
         # below onto the delivered mix when the energy layer is on)
         ela_forecast = plan.region_intensity
     if energy is not None:
-        spec_e, sres, solar_mat, cap_cols, ceff_cols = _prepare_energy(
-            energy, family, plan, comp, T, cfg_base.interval_s, grid_up,
-            region_mat=(fault_ctx.true_reg if fault_ctx is not None
-                        else None))
+        with obs.span("sweep.energy"):
+            spec_e, sres, solar_mat, cap_cols, ceff_cols = _prepare_energy(
+                energy, family, plan, comp, T, cfg_base.interval_s,
+                grid_up, region_mat=(fault_ctx.true_reg
+                                     if fault_ctx is not None else None))
         energy_summary = sres.summary()
         if elasticity is None:
             # in-scan fold: the scan re-derives the supply ledger on
@@ -1165,7 +1173,7 @@ def sweep_population_jax(policies: dict, family: SliceFamily, traces,
             # fleet backend; billing (and the carbon forecast) switch
             # to the delivered mix's effective intensity
             comp = comp * cap_cols
-            carbon = (sres.c_eff, plan.assign.astype(np.int32))
+            carbon = (sres.c_eff, plan.assign)
             if fault_ctx is not None:
                 # observed delivered mix: true effective intensity
                 # scaled by the per-region observed/true grid ratio —
@@ -1188,11 +1196,12 @@ def sweep_population_jax(policies: dict, family: SliceFamily, traces,
         # energy on, `carbon` is the (c_eff, codes) indexed pair, so
         # both the actual intensity and its forecast see the delivered
         # mix — exactly like the fleet backend's ceff_reg forecast.
+        with obs.span("sweep.elastic_budget"):
+            budget = _elastic_budget_series(plan, T, elasticity,
+                                            cfg_base.interval_s)
         eres = simulate_elastic_jax(comp, carbon, elasticity,
                                     cfg_base.interval_s,
-                                    budget_series=_elastic_budget_series(
-                                        plan, T, elasticity,
-                                        cfg_base.interval_s),
+                                    budget_series=budget,
                                     carbon_forecast=ela_forecast)
         demand_one = eres.demand_served()
         demand_scale = 1.0          # already applied ahead of the layer
@@ -1217,6 +1226,7 @@ def sweep_population_jax(policies: dict, family: SliceFamily, traces,
         if plan is not None and plan.failed_migrations is not None:
             fault_summary["fault_failed_migrations_mean"] = float(
                 np.mean(plan.failed_migrations))
-    return _aggregate_sweep_rows(policies, results, targets, n_tr, plan,
-                                 traffic_summary, elastic_summary,
-                                 energy_summary, fault_summary)
+    with obs.span("sweep.aggregate"):
+        return _aggregate_sweep_rows(policies, results, targets, n_tr, plan,
+                                     traffic_summary, elastic_summary,
+                                     energy_summary, fault_summary)

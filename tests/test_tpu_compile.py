@@ -13,6 +13,8 @@ The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and the test workers all
 import this file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -91,6 +93,12 @@ def test_plan_scan_compiles_with_the_kernel(compile_for, S):
         mult_b=1.0, h_hr=1.0, hk=1.1, admission_impl="pallas",
         block_n=8192, interpret=False, has_faults=True, bb=1, bc=8)
     assert "tpu_custom_call" in text
+    # the round keeps its name in the compiled program (and so in the
+    # profiler's trace): one custom call, reached once per round
+    calls = re.findall(r"^\s*%admission_round[\w.]* = .*custom-call\(.*"
+                       r'custom_call_target="tpu_custom_call"', text,
+                       flags=re.M)
+    assert len(calls) == 1
 
 
 def test_indexed_fleet_scan_compiles_at_1m(compile_for, S):
