@@ -86,8 +86,15 @@ class PlacementPlan:
     `assign[n, i]` is container i's region during epoch n (post-decision:
     a move decided at epoch n serves epoch n from the destination, with
     the stop-and-copy downtime priced into `overhead_g`/`downtime_s`).
+
+    The JAX planner leaves its (T, N) int32 assignments on the device, in
+    `assign_device`, where the fleet scan reads them, and passes
+    ``assign=None``: `assign` is then made from the device copy on first
+    read (pulled, widened to int64) and kept, a writable host array like
+    the NumPy planner's. `demand_device` pairs the host demand that
+    planner was given with the device copy it pushed.
     """
-    assign: np.ndarray               # (T, N) int64 region index
+    assign: Optional[np.ndarray]     # (T, N) int64 region index
     migrations: np.ndarray           # (N,) placement moves per container
     overhead_g: np.ndarray           # (N,) stop-and-copy emissions (g)
     downtime_s: np.ndarray           # (N,) stop-and-copy downtime (s)
@@ -98,6 +105,8 @@ class PlacementPlan:
     # (T,) capacity-admission preference rounds per epoch; kept by the JAX
     # planner only, and None where no admission ran (uncapped, trivial)
     admission_rounds: Optional[np.ndarray] = None
+    assign_device: Optional[object] = None   # (T, N) int32 jax.Array
+    demand_device: Optional[tuple] = None    # (host (T, N), device copy)
 
     @property
     def n_regions(self) -> int:
@@ -116,6 +125,21 @@ class PlacementPlan:
         for r in range(R):
             out[:, r] = (self.assign == r).sum(axis=1)
         return out
+
+
+def _read_assign(plan) -> Optional[np.ndarray]:
+    if plan._assign is None and plan.assign_device is not None:
+        plan._assign = np.asarray(plan.assign_device).astype(np.int64)
+    return plan._assign
+
+
+def _write_assign(plan, value) -> None:
+    plan._assign = value
+
+
+# `assign` stays a dataclass field (the constructor sets it through the
+# setter); the property makes the JAX planner's host copy on first read
+PlacementPlan.assign = property(_read_assign, _write_assign)
 
 
 @dataclass

@@ -19,8 +19,11 @@ one `jax.lax.scan` over epochs:
     reverse-differentiable as-is — switch to a fixed-trip fori_loop
     first if you need gradients through admission;
   - one host->device push of (cmat, demand, cost0, mig_s), one pull of
-    the final carry, the (T, N) int32 assignment matrix and the (T,)
-    count of preference rounds each epoch ran.
+    the final carry and the (T,) count of preference rounds each epoch
+    ran. The (T, N) int32 assignment matrix and the pushed demand stay on
+    the device, in the plan, for the fleet scan to take up
+    (`repro.core.fleet_jax.sweep_population_jax`); the plan's host
+    `assign` is pulled from them only when something reads it.
 
 Why the ranked admission is the one hot path XLA handles badly
 --------------------------------------------------------------
@@ -339,6 +342,11 @@ def plan_jax(engine, demand, state_gb: float = 1.0, initial=None,
     pay stop-and-copy but stay put and retry under capped exponential
     backoff; parity with the NumPy planner is preserved because the
     mask derivation is shared.
+
+    It pulls the final carry and the (T,) admission rounds only. The
+    (T, N) int32 assignments stay on the device as `assign_device`, and
+    the pushed demand as `demand_device`, beside the host array it was
+    pushed from; `assign` is pulled from `assign_device` on first read.
     """
     with obs.span("plan"):
         with obs.span("plan.prepare"):
@@ -348,21 +356,22 @@ def plan_jax(engine, demand, state_gb: float = 1.0, initial=None,
         if call is None:
             return _trivial_plan(engine, cmat, assign0,
                                  has_faults=has_faults)
-        args, kw = call
+        host_args, kw = call
         with jax.enable_x64(True):
             with obs.span("plan.h2d"):
-                args = jax.block_until_ready(jax.device_put(args))
+                args = jax.block_until_ready(jax.device_put(host_args))
                 obs.count("h2d_bytes", obs.nbytes(args))
             with obs.span("plan.wait"):
-                out = jax.block_until_ready(_plan_scan(*args, **kw))
+                carry, assign_mat, rounds = jax.block_until_ready(
+                    _plan_scan(*args, **kw))
             with obs.span("plan.d2h"):
-                carry, assign_mat, rounds = jax.device_get(out)
-                obs.count("d2h_bytes", obs.nbytes((carry, assign_mat,
-                                                   rounds)))
+                # the (T, N) assignments stay on the device
+                carry, rounds = jax.device_get((carry, rounds))
+                obs.count("d2h_bytes", obs.nbytes((carry, rounds)))
                 if rounds is not None:
                     obs.count("admission_rounds", rounds.sum())
                 return PlacementPlan(
-                    assign=assign_mat.astype(np.int64),
+                    assign=None,
                     migrations=carry[2].astype(np.int64),
                     overhead_g=carry[3],
                     downtime_s=carry[4],
@@ -371,7 +380,9 @@ def plan_jax(engine, demand, state_gb: float = 1.0, initial=None,
                     initial=assign0.copy(),
                     failed_migrations=(carry[8].astype(np.int64)
                                        if has_faults else None),
-                    admission_rounds=rounds)
+                    admission_rounds=rounds,
+                    assign_device=assign_mat,
+                    demand_device=(host_args[1], args[1]))
 
 
 def lower_plan(engine, demand, state_gb: float = 1.0, initial=None,

@@ -14,7 +14,10 @@ ports the decision kernels and the epoch loop to JAX:
   - everything runs float64 (`jax.enable_x64`, scoped so
     the f32 model/kernel suites are untouched) and device-resident: one
     host->device push of the inputs, one device->host pull of the final
-    state.
+    state. In a placed sweep the region codes and the demand are already
+    on the device, left there by the JAX planner, and the scan takes
+    them up without a round trip through the host; only the small inputs
+    go up (the (T, R) region tables, the (N,) targets, epsilon and state).
 
 Branchy NumPy fast paths (`if np.count_nonzero(...)` gates, the
 compacted `_best_fit_up_batch` walk, the closed-form dispatch for
@@ -836,7 +839,8 @@ class FleetSimulatorJax:
     def run(self, policy, demand, carbon, targets, epsilon=0.05,
             state_gb=1.0, demand_scale=1.0, record: bool = False,
             n_rep: int = 1, traffic=None, energy=None,
-            carbon_obs=None, power_gap=None) -> FleetResult:
+            carbon_obs=None, power_gap=None,
+            demand_device=None) -> FleetResult:
         """Advance the fleet; same contract as `FleetSimulator.run`, plus
         the memory-lean indexed-carbon form: `carbon` may be a
         ``(region_mat (T, R), codes (T, n_cols) int)`` pair — a
@@ -845,6 +849,12 @@ class FleetSimulatorJax:
         matrix and ``n_rep`` tiles its columns inside the scan step to
         the logical fleet width N = n_cols * n_rep (targets/epsilon/
         state_gb are full-N). No (T, N) array exists on host or device.
+        The codes may be a device array (a `jax.Array`, such as the
+        JAX planner's `PlacementPlan.assign_device`), and
+        `demand_device` the device copy of the compact `demand`, pushed
+        and checked already (`PlacementPlan.demand_device`): the run
+        then takes them as they are, with no host cast, check or push,
+        and reads the host `demand` only for `work_demanded`.
 
         `traffic` (indexed-carbon runs only) is a ``(TrafficSpec,
         req_mat (T, R))`` pair: the scan then also routes + autoscales
@@ -883,15 +893,23 @@ class FleetSimulatorJax:
                 if demand.ndim != 2:
                     raise ValueError("indexed-carbon run needs (T, n_cols) "
                                      "demand")
-                if demand_scale is not None and np.any(
-                        np.asarray(demand_scale) != 1.0):
-                    demand = demand * demand_scale
-                if demand.size and demand.min() < 0.0:
-                    raise ValueError("fleet demand must be non-negative")
+                scaled = demand_scale is not None and np.any(
+                    np.asarray(demand_scale) != 1.0)
+                if demand_device is not None:
+                    if scaled or demand_device.shape != demand.shape:
+                        raise ValueError("the device demand must be a copy "
+                                         "of the demand, unscaled")
+                else:
+                    if scaled:
+                        demand = demand * demand_scale
+                    if demand.size and demand.min() < 0.0:
+                        raise ValueError("fleet demand must be "
+                                         "non-negative")
                 T, n_cols = demand.shape
                 N = n_cols * int(n_rep)
                 region_mat = np.asarray(region_mat, dtype=np.float64)
-                codes = np.asarray(codes, dtype=np.int32)
+                if not isinstance(codes, jax.Array):
+                    codes = np.asarray(codes, dtype=np.int32)
                 if region_mat.ndim != 2 or region_mat.shape[0] != T:
                     raise ValueError(f"region matrix shape {region_mat.shape}"
                                      f" does not match demand (T={T})")
@@ -925,6 +943,9 @@ class FleetSimulatorJax:
             else:
                 if n_rep != 1:
                     raise ValueError("n_rep tiling requires indexed carbon")
+                if demand_device is not None:
+                    raise ValueError("a device demand requires indexed "
+                                     "carbon")
                 (demand, cmat, targets, epsilon, state_gb, T, N) = \
                     _prepare_run_inputs(demand, carbon, targets, epsilon,
                                         state_gb, demand_scale, self.interval_s)
@@ -970,9 +991,11 @@ class FleetSimulatorJax:
                         lo_r = s * n_rep // n_sh
                         hi_r = (s + 1) * n_rep // n_sh
                         lo, hi = lo_r * n_cols, hi_r * n_cols
-                        args = (demand, (region_mat, codes), targets[lo:hi],
-                                epsilon[lo:hi], state_gb[lo:hi], req_mat,
-                                solar_mat, up_mat, carbon_obs, power_gap)
+                        args = (demand if demand_device is None
+                                else demand_device, (region_mat, codes),
+                                targets[lo:hi], epsilon[lo:hi],
+                                state_gb[lo:hi], req_mat, solar_mat, up_mat,
+                                carbon_obs, power_gap)
                         static = dict(cmode="indexed", n_rep=hi_r - lo_r,
                                       R=R, traffic=t_spec, energy=e_spec)
                     else:
@@ -987,11 +1010,17 @@ class FleetSimulatorJax:
                                 state_gb[lo:hi], None, None, None, ob,
                                 power_gap)
                         static = {}
+                    # device arrays are taken up where they are (or copied
+                    # device to device to another shard's chip)
+                    leaves = jax.tree_util.tree_leaves(args)
+                    handed = obs.nbytes([x for x in leaves
+                                         if isinstance(x, jax.Array)])
+                    obs.count("handoff_bytes", handed)
+                    obs.count("h2d_bytes", obs.nbytes(leaves) - handed)
                     shards.append(jax.device_put(args, devices[s]))
                     statics.append(static)
                 # every shard's push is in flight before the one wait
                 shards = jax.block_until_ready(shards)
-                obs.count("h2d_bytes", obs.nbytes(shards))
             outs = [_fleet_scan(*args, **static, **kw)
                     for args, static in zip(shards, statics)]
             with obs.span("fleet.wait"):
@@ -1066,7 +1095,9 @@ def sweep_population_jax(policies: dict, family: SliceFamily, traces,
     scan step, so no (T, N) matrix is ever materialized (the fleet
     backend's tiled form is ~2.3 GB per matrix at N=1M). The indexed
     select reproduces the gathered matrix bit-exactly, so sweep parity
-    with the fleet backend is unchanged. `admission_impl` is forwarded
+    with the fleet backend is unchanged. The codes are the plan's device
+    assignments, and the demand its device copy where the scan runs on
+    the very demand the plan was given: neither goes through the host. `admission_impl` is forwarded
     to `plan_jax` ("auto" | "xla" | "pallas").
 
     With `faults` (a `repro.robustness.FaultPlan`), the observed/true
@@ -1092,13 +1123,16 @@ def sweep_population_jax(policies: dict, family: SliceFamily, traces,
     carbon_obs = None
     gap_vec = fault_ctx.gap_vec if fault_ctx is not None else None
     if compact:
+        # the scan reads the plan's codes where the planner left them
+        codes = (plan.assign if plan.assign_device is None
+                 else plan.assign_device)
         if fault_ctx is None:
-            carbon = (plan.region_intensity, plan.assign)
+            carbon = (plan.region_intensity, codes)
         else:
             # bill at the TRUE region intensities; the plan's own table
             # (region_intensity) IS the observed feed under faults and
             # becomes the scan's decision signal
-            carbon = (fault_ctx.true_reg, plan.assign)
+            carbon = (fault_ctx.true_reg, codes)
             carbon_obs = plan.region_intensity
         n_rep = n_tg
     elif fault_ctx is not None:
@@ -1173,7 +1207,7 @@ def sweep_population_jax(policies: dict, family: SliceFamily, traces,
             # fleet backend; billing (and the carbon forecast) switch
             # to the delivered mix's effective intensity
             comp = comp * cap_cols
-            carbon = (sres.c_eff, plan.assign)
+            carbon = (sres.c_eff, codes)
             if fault_ctx is not None:
                 # observed delivered mix: true effective intensity
                 # scaled by the per-region observed/true grid ratio —
@@ -1199,7 +1233,9 @@ def sweep_population_jax(policies: dict, family: SliceFamily, traces,
         with obs.span("sweep.elastic_budget"):
             budget = _elastic_budget_series(plan, T, elasticity,
                                             cfg_base.interval_s)
-        eres = simulate_elastic_jax(comp, carbon, elasticity,
+        # the elasticity scan gathers on the host: the plan's host codes
+        eres = simulate_elastic_jax(comp, (carbon[0], plan.assign),
+                                    elasticity,
                                     cfg_base.interval_s,
                                     budget_series=budget,
                                     carbon_forecast=ela_forecast)
@@ -1207,6 +1243,13 @@ def sweep_population_jax(policies: dict, family: SliceFamily, traces,
         demand_scale = 1.0          # already applied ahead of the layer
         elastic_summary = eres.summary()
 
+    # the planner's device demand stands in for a push where the run
+    # would push the very array the plan was given: not after
+    # elasticity, nor under a demand_scale (each makes a new array)
+    demand_device = None
+    if plan is not None and plan.demand_device is not None \
+            and plan.demand_device[0] is demand_one:
+        demand_device = plan.demand_device[1]
     sim = FleetSimulatorJax(
         family, interval_s=cfg_base.interval_s,
         suspend_releases_slice=cfg_base.suspend_releases_slice)
@@ -1219,7 +1262,8 @@ def sweep_population_jax(policies: dict, family: SliceFamily, traces,
                                  n_rep=n_rep, traffic=run_traffic,
                                  energy=run_energy,
                                  carbon_obs=carbon_obs,
-                                 power_gap=gap_vec), 0)
+                                 power_gap=gap_vec,
+                                 demand_device=demand_device), 0)
     fault_summary = None
     if fault_ctx is not None:
         fault_summary = fault_ctx.signal.summary()

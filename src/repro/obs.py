@@ -13,12 +13,15 @@ equality):
           plan.prepare       host inputs of the plan
           plan.h2d           its arguments pushed to the device
           plan.wait          the plan's program, dispatched and awaited
-          plan.d2h           its outputs pulled, the `PlacementPlan` built
+          plan.d2h           its carry and rounds pulled, the
+                             `PlacementPlan` built (the assignments stay
+                             on the device)
       sweep.traffic          `fleet._prepare_traffic`
       sweep.energy           `fleet._prepare_energy`
       sweep.elastic_budget   `fleet._elastic_budget_series`
       fleet.prepare          host inputs of `FleetSimulatorJax.run`
-      fleet.h2d              its arguments pushed, per shard
+      fleet.h2d              its host arguments pushed, per shard (the
+                             planner's device arrays are taken up)
       fleet.wait             the fleet scans awaited
       fleet.d2h              the scans' carries pulled and joined
       fleet.result           the `FleetResult` built
@@ -28,6 +31,9 @@ Counters are kept per sweep, in memory, and start from zero when `sweep`
 opens; `last_sweep()` returns a copy:
 
     h2d_bytes, d2h_bytes     bytes the h2d and d2h spans move
+    handoff_bytes            bytes the fleet scan took from the planner's
+                             device arrays (codes, demand) in place of a
+                             push from the host
     admission_rounds         preference rounds of the plan, over its epochs
 """
 from __future__ import annotations
@@ -36,7 +42,7 @@ import contextlib
 
 import jax
 
-COUNTERS = ("h2d_bytes", "d2h_bytes", "admission_rounds")
+COUNTERS = ("h2d_bytes", "d2h_bytes", "handoff_bytes", "admission_rounds")
 
 span = jax.profiler.TraceAnnotation
 

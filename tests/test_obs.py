@@ -131,12 +131,14 @@ def test_transfer_bytes_are_the_arrays_moved(traced):
                + n * i4 + R * i4 + R * i4      # initial region, occ, cap
                + 2 * n * f8)                   # cost0, mig_s
     plan_down = (3 * n * i4 + 2 * n * f8 + R * i4   # final carry
-                 + T * n * i4 + T * i4)             # assignments, rounds
-    fleet_up = (T * n * f8 + T * R * f8 + T * n * i4   # demand, carbon
+                 + T * i4)                          # rounds
+    fleet_up = (T * R * f8                    # region carbon
                 + 3 * N * f8)                 # targets, epsilon, state
     fleet_down = 4 * N * f8 + (S + 7) * N * i4     # acc, dyni
     assert counts["h2d_bytes"] == plan_up + fleet_up
     assert counts["d2h_bytes"] == plan_down + fleet_down
+    # the plan's assignments and demand stay on the device for the scan
+    assert counts["handoff_bytes"] == T * n * (f8 + i4)
 
 
 def _plan_inputs(n, seed=13):
