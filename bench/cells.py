@@ -2,8 +2,13 @@
 
 `BENCHMARK.json` names the cells; a cell's configuration is the file its
 `configs` entry names, its traffic mix is `bench/mixes/<traffic>.json`, and
-each per-layer metric is read by `bench/metrics/<name>.py`. A new cell,
-mix or metric is a new file and a new entry, never an edit of a file.
+each per-layer metric is read by `bench/metrics/<name>.py`. A mix's
+`layers` maps layer names to their parameters; each layer is
+`bench/layers/<layer>.py` (its inputs and its program settings, see
+`bench.layers`), and the mix's `reference` names `bench/ref/<reference>.py`
+(`placed` where the mix names none), which recomputes the cell's answers
+and lists the layers it models. A new cell, mix, layer, reference or
+metric is a new file and a new entry, never an edit of a file.
 """
 from __future__ import annotations
 
@@ -43,16 +48,37 @@ def mix(name: str) -> dict:
     return json.loads(path.read_text())
 
 
-def reader(name: str):
-    """The module that reads per-layer metric `name` (bench/metrics)."""
-    path = BENCH / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"bench.metrics.{name}", path)
-    if spec is None or not path.exists():
-        raise KeyError(f"no reader for metric {name!r} ({path} is missing)")
+def _module(kind: str, name: str, what: str):
+    """`bench/<kind>/<name>.py`, loaded from its file."""
+    path = BENCH / kind / f"{name}.py"
+    if not path.exists():
+        raise KeyError(f"no {what} {name!r} ({path} is missing)")
+    spec = importlib.util.spec_from_file_location(f"bench.{kind}.{name}", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def reader(name: str):
+    """The module that reads per-layer metric `name` (bench/metrics)."""
+    return _module("metrics", name, "reader for metric")
+
+
+def reference(mix: dict):
+    """The module that recomputes `mix`'s answers (bench/ref). Refuses a
+    mix that turns on a layer the reference does not model."""
+    name = mix.get("reference", "placed")
+    ref = _module("ref", name, "reference")
+    extra = sorted(set(mix["layers"]) - set(ref.LAYERS))
+    if extra:
+        raise ValueError(f"mix {mix['name']!r} turns on layers {extra}, "
+                         f"which its reference {name!r} does not model")
+    return ref
+
+
+def layers(mix: dict) -> dict:
+    """The modules of the layers `mix` turns on, by name (bench/layers)."""
+    return {name: _module("layers", name, "layer") for name in mix["layers"]}
 
 
 def end_to_end(bench: dict, cell: str) -> list:

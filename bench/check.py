@@ -3,19 +3,19 @@
 The timed path's answers are the sweep's aggregate rows, one per (target,
 policy), and the region plan the sweep computed. Every sweep of the window
 must return the warm-up sweep's rows. Once the window has closed, the
-reference (`bench.ref.placed`) recomputes the plan and the rows of a sample
-of the targets, drawn from the seed: each sampled row is compared key by
-key (counts exactly, every other number by its relative gap), the window's
-last plan assignment by assignment, and the warm-up's and the window's last
-plan are held to each region's capacity in every epoch.
+cell's reference (`bench/ref/<reference>.py`, found through its mix)
+recomputes the rows of a sample of the targets, drawn from the seed, and
+the plan where the mix has one: each sampled row is compared key by key
+(the reference's `COUNT_KEYS` exactly, every other number by its relative
+gap), the window's last plan assignment by assignment, and the warm-up's
+and the window's last plan are held to each region's capacity in every
+epoch.
 """
 from __future__ import annotations
 
 import numpy as np
 
-# Row keys that hold counts (means of integer counts over the same
-# containers): a different decision anywhere shows here as an inequality.
-COUNT_KEYS = ("migrations_mean", "placement_migrations_mean")
+from bench.ref.placed import COUNT_KEYS
 
 
 def sampled_targets(targets, k: int, seed: int) -> list:
@@ -35,10 +35,11 @@ def _flat(row: dict) -> dict:
     return out
 
 
-def rows_gap(got: list, ref: list) -> dict:
+def rows_gap(got: list, ref: list, count_keys=COUNT_KEYS) -> dict:
     """Compare rows matched by (policy, target): the worst relative gap
-    |a - b| / max(|b|, 1) over every number, the number of count keys that
-    differ, and the number of rows or keys present on one side only."""
+    |a - b| / max(|b|, 1) over every number, the number of `count_keys`
+    that differ, and the number of rows or keys present on one side
+    only."""
     gap, counts, missing = 0.0, 0, 0
     by_key = {(r["policy"], float(r["target"])): r for r in got}
     worst = None
@@ -60,7 +61,7 @@ def rows_gap(got: list, ref: list) -> dict:
             if not (np.isfinite(x) and np.isfinite(y)):
                 missing += 0 if x == y else 1
                 continue
-            if k in COUNT_KEYS and x != y:
+            if k in count_keys and x != y:
                 counts += 1
             g = abs(x - y) / max(abs(y), 1.0)
             if g > gap:
@@ -87,26 +88,28 @@ def plan_mismatches(got: np.ndarray, ref: np.ndarray) -> int:
 
 
 def judge(warm: list, sweeps: list, ref: list, limits: dict,
-          window_compiles: int, plans: dict) -> dict:
+          window_compiles: int, plans: dict, count_keys=COUNT_KEYS) -> dict:
     """Every number compared, each with its limit, and the verdict.
 
     `plans` holds `plan_mismatches` and `over_capacity_epochs`, read from
-    the timed path's plans. `failed` counts the window's sweeps whose rows
-    differ from the warm-up sweep's rows."""
+    the timed path's plans, or is None where the reference has no plan.
+    `failed` counts the window's sweeps whose rows differ from the warm-up
+    sweep's rows."""
     failed = sum(1 for rows in sweeps if rows != warm)
-    cmp = rows_gap(sweeps[0] if sweeps else [], ref)
+    cmp = rows_gap(sweeps[0] if sweeps else [], ref, count_keys)
     checks = {
         "row_rel_gap": (cmp["row_rel_gap"], limits["row_rel_gap"]),
         "count_mismatches": (cmp["count_mismatches"],
                              limits["count_mismatches"]),
-        "plan_mismatches": (plans["plan_mismatches"],
-                            limits["plan_mismatches"]),
-        "over_capacity_epochs": (plans["over_capacity_epochs"],
-                                 limits["over_capacity_epochs"]),
+    }
+    if plans is not None:
+        checks.update({k: (plans[k], limits[k]) for k in (
+            "plan_mismatches", "over_capacity_epochs")})
+    checks.update({
         "missing_keys": (cmp["missing"], 0),
         "failed_sweeps": (failed, 0),
         "window_compiles": (window_compiles, 0),
-    }
+    })
     correct = bool(sweeps) and all(v <= lim for v, lim in checks.values())
     return {"correct": correct, "failed": failed, "checks": checks,
             "worst_key": cmp["worst_key"]}

@@ -49,6 +49,12 @@ import numpy as np
 
 MOVE, STAY, SUSPEND, RESUME = 0, 1, 2, 3
 
+# The layers this reference models besides placement: none.
+LAYERS = ()
+# Row keys that hold counts (means of integer counts over the same
+# containers): a different decision anywhere shows here as an inequality.
+COUNT_KEYS = ("migrations_mean", "placement_migrations_mean")
+
 
 class Servers:
     """The slice family: capacity multiples of the baseline server, with

@@ -5,17 +5,21 @@
 The run prints the device JAX sees and stops with exit code 2, printing no
 result, unless that is a TPU with as many chips as the cell asks for. Then:
 
-1. set-up: make the cell's inputs from the seed (`bench.gen`), build the
-   sweep (`SweepSpec(backend="jax")`, `bench.sweep`) and run it once, so
-   that every program the window drives is compiled or read from JAX's
-   persistent cache (kept in the checkout by `repro.compile_cache`);
+1. set-up: find the cell's reference through its traffic mix and refuse
+   a mix that turns on a layer the reference does not model; make the
+   cell's inputs from the seed (`bench.gen`, with each layer's own), build
+   the sweep (`SweepSpec(backend="jax")` with each layer's settings,
+   `bench.sweep`) and run it once, so that every program the window
+   drives is compiled or read from JAX's persistent cache (kept in the
+   checkout by `repro.compile_cache`);
 2. window: run whole sweeps back to back until `--seconds` have passed; a
    sweep cannot be split, so the window ends on the first sweep boundary at
    or after `--seconds`. With `--trace 1` the window is one sweep under the
-   profiler, and the per-layer metrics are read from its trace;
-3. check: the region plan and a sample of the rows, drawn from the seed,
-   are recomputed by the reference (`bench.ref.placed`) and compared
-   (`bench.check`).
+   profiler, and the per-layer metrics are read from its trace. Each
+   sweep's duration is printed on standard error;
+3. check: a sample of the rows, drawn from the seed, and the region plan
+   where the reference makes one, are recomputed by the mix's reference
+   (`bench/ref/<reference>.py`) and compared (`bench.check`).
 
 A traced run fails, printing no result, when a per-layer metric that
 `BENCHMARK.json` lists for the cell reads nothing.
@@ -68,6 +72,16 @@ def _sweep(spec):
     return spec.run().rows
 
 
+def _lapped(step, laps: list):
+    """`step`, appending the seconds each call takes to `laps`."""
+    def lapped():
+        t = time.perf_counter()
+        out = step()
+        laps.append(time.perf_counter() - t)
+        return out
+    return lapped
+
+
 def window(step, seconds: float, clock=time.perf_counter):
     """Call `step` back to back until `seconds` have passed; a call is never
     cut, so the window ends on the first call boundary at or after
@@ -111,11 +125,12 @@ def run_cell(bench: dict, name: str, seed: int, seconds: float, trace: bool,
     cell = cells.workload(bench, name)
     cfg = {**cells.config(bench, cell["config"]), **(sizes or {})}
     mix = cells.mix(cell["traffic"])
+    ref = cells.reference(mix)
     used = devices[:cell["chips"]]
     clock = CompileClock()
 
     t = time.perf_counter()
-    inputs = make_inputs(cfg, seed)
+    inputs = make_inputs(cfg, seed, mix)
     gen_s = time.perf_counter() - t
     spec = sweep.program_sweep(cfg, mix, inputs)
     with sweep.PlanTap() as tap:
@@ -128,22 +143,25 @@ def run_cell(bench: dict, name: str, seed: int, seconds: float, trace: bool,
               f"cache_hits={hits}", file=sys.stderr, flush=True)
 
         traced = None
+        laps = []
+        step = _lapped(lambda: _sweep(spec), laps)
         if trace:
             with tempfile.TemporaryDirectory(prefix="bench_trace_") as tdir:
                 jax.profiler.start_trace(tdir)
                 with jax.profiler.TraceAnnotation(SPAN):
-                    sweeps, window_s = window(lambda: _sweep(spec), 0.0)
+                    sweeps, window_s = window(step, 0.0)
                 jax.profiler.stop_trace()
                 traced = tr.from_xplane(sorted(glob.glob(
                     f"{tdir}/plugins/profile/*/*.xplane.pb"))[-1], SPAN)
         else:
-            sweeps, window_s = window(lambda: _sweep(spec), seconds)
+            sweeps, window_s = window(step, seconds)
         last_plan = tap.last
     window_compiles = clock.snapshot()[0] - compiles0
     stats = [d.memory_stats() for d in used]
     mem = max((s or {}).get("peak_bytes_in_use", 0) for s in stats)
     print(f"window: sweeps={len(sweeps)} window_s={window_s!r} "
           f"compiles={window_compiles}", file=sys.stderr, flush=True)
+    print(f"window: sweep_s={laps!r}", file=sys.stderr, flush=True)
 
     T, n_tr = inputs["traces"].shape
     n = n_tr * len(inputs["targets"])
@@ -153,16 +171,19 @@ def run_cell(bench: dict, name: str, seed: int, seconds: float, trace: bool,
     t = time.perf_counter()
     targets = check.sampled_targets(inputs["targets"], mix["check_targets"],
                                     seed)
-    ref, ref_plan = placed.sweep(cfg, inputs, targets)
-    R, cap = len(cfg["regions"]), placed.capacity(cfg)
-    got = [getattr(p, "assign", None) for p in (warm_plan, last_plan)]
-    plans = {
-        "plan_mismatches": sum(check.plan_mismatches(a, ref_plan["assign"])
-                               for a in got),
-        "over_capacity_epochs": sum(check.over_capacity_epochs(a, R, cap)
-                                    for a in got if a is not None)}
-    verdict = check.judge(warm, sweeps, ref, cfg["limits"], window_compiles,
-                          plans)
+    ref_rows, ref_plan = ref.sweep(cfg, inputs, targets)
+    plans = None
+    if ref_plan is not None:
+        R, cap = len(cfg["regions"]), placed.capacity(cfg)
+        got = [getattr(p, "assign", None) for p in (warm_plan, last_plan)]
+        plans = {
+            "plan_mismatches": sum(
+                check.plan_mismatches(a, ref_plan["assign"]) for a in got),
+            "over_capacity_epochs": sum(
+                check.over_capacity_epochs(a, R, cap)
+                for a in got if a is not None)}
+    verdict = check.judge(warm, sweeps, ref_rows, cfg["limits"],
+                          window_compiles, plans, ref.COUNT_KEYS)
     print(f"check: targets={targets} reference_s={time.perf_counter() - t!r} "
           f"worst_key={verdict['worst_key']}", file=sys.stderr, flush=True)
 

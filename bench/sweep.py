@@ -1,11 +1,14 @@
 """Build one cell's sweep on the program under test from its configuration
 file and generated inputs, and keep the region plan the sweep computes.
 
-The timed path is `SweepSpec.run()` on the JAX backend. The reference
-(`bench.ref.placed`) reads the same configuration file on its own.
+The timed path is `SweepSpec.run()` on the JAX backend: the placed sweep,
+with the keywords each layer the mix turns on sets (`bench.layers`). The
+mix's reference (`bench/ref/`) reads the same configuration file on its
+own.
 """
 from __future__ import annotations
 
+from bench import cells
 from bench.ref.placed import capacity
 
 
@@ -22,16 +25,15 @@ def family(cfg: dict):
 
 
 def program_sweep(cfg: dict, mix: dict, inputs: dict):
-    """The timed path: a `SweepSpec` on the JAX backend over every target."""
+    """The timed path: a `SweepSpec` on the JAX backend over every target,
+    with each of the mix's layers. Refuses a layer its reference does not
+    model, a layer with no file, and a keyword set twice."""
     from repro.cluster.migration import MigrationCostModel
     from repro.cluster.placement import PlacementConfig, PlacementEngine
     from repro.core.policy import CarbonContainerPolicy
     from repro.core.simulator import SimConfig
     from repro.core.spec import SweepSpec
-    if mix["layers"]:
-        raise ValueError(f"mix {mix['name']!r} turns on layers "
-                         f"{sorted(mix['layers'])}, which the reference "
-                         f"does not model")
+    cells.reference(mix)
     fam = family(cfg)
     sim, pol, p = cfg["sim"], cfg["policy"], cfg["placement"]
     if pol["name"] != "carbon_containers":
@@ -41,13 +43,23 @@ def program_sweep(cfg: dict, mix: dict, inputs: dict):
         migration=MigrationCostModel(**cfg["migration"]),
         config=PlacementConfig(capacity=capacity(cfg), **p),
         region_names=tuple(cfg["regions"]))
-    return SweepSpec(
+    kw = dict(
         backend="jax", family=fam, traces=inputs["traces"],
         targets=list(inputs["targets"]), placement=engine,
         policies={pol["name"]: lambda: CarbonContainerPolicy(
             variant=pol["variant"], min_dwell=pol["min_dwell"],
             idle_margin=pol["idle_margin"])},
         sim=SimConfig(target_rate=0.0, **sim))
+    owner = dict.fromkeys(kw, "the placed sweep")
+    for name, layer in cells.layers(mix).items():
+        got = layer.program(cfg, mix["layers"][name], inputs)
+        for k in got:
+            if k in owner:
+                raise ValueError(f"layer {name!r} sets {k!r}, which "
+                                 f"{owner[k]} sets too")
+            owner[k] = f"layer {name!r}"
+        kw.update(got)
+    return SweepSpec(**kw)
 
 
 class PlanTap:
