@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro import obs
 from repro.carbon.forecast import forecast_series
 from repro.core.elasticity import (ElasticityConfig, ElasticResult,
                                    shaped_budget_series)
@@ -159,30 +160,11 @@ def _budget_array(budget_series, cfg: ElasticityConfig, dt: float,
     return np.full(T, float(cfg.budget_g_per_epoch))
 
 
-def simulate_elastic_jax(demand, carbon, cfg: ElasticityConfig,
-                         interval_s: float = 300.0,
-                         record: bool = False,
-                         budget_series=None,
-                         carbon_forecast=None) -> ElasticResult:
-    """JAX port of `repro.core.elasticity.simulate_elastic`.
-
-    demand : (T, N) demand rate (host array)
-    carbon : dense (T, N), or `(region_mat (T, R), codes (T, N))` for
-             the placed-fleet indexed layout
-    With `record=False` the per-epoch levels are not streamed out
-    (`ElasticResult.levels` is empty) — the summary totals still
-    include them via an in-scan accumulator.
-    `budget_series` overrides the per-epoch budgets (see
-    `simulate_elastic`); when omitted and `cfg.shape_budget` is set it
-    is derived host-side from the mean-over-containers carbon signal,
-    matching the NumPy backend bit for bit.
-    `carbon_forecast` overrides the matrix the carbon forecaster runs
-    on — the scaler then plans against that signal while billing
-    `carbon` (the observed/true split under signal-plane faults):
-    (T, R) region form in indexed mode, (T, N) dense otherwise. The
-    fleet backend forecasts the very same matrix host-side, so the two
-    stay bit-identical (forecast-then-gather on both).
-    """
+def _prepare(demand, carbon, cfg: ElasticityConfig, interval_s: float,
+             budget_series, carbon_forecast):
+    """The scan's host inputs `xs`: the demand and its forecast, the
+    budgets, and the carbon (dense, or the indexed pair) with its
+    forecast; and the sizes (T, n, R), R None where the carbon is dense."""
     demand = np.asarray(demand, dtype=np.float64)
     if demand.ndim != 2:
         raise ValueError(f"demand must be (T, N); got {demand.shape}")
@@ -235,25 +217,62 @@ def simulate_elastic_jax(demand, carbon, cfg: ElasticityConfig,
                             lambda: carbon.mean(axis=1))
         xs = (demand, dhat, bud, carbon, chat)
 
+    return xs, T, n, R
+
+
+def simulate_elastic_jax(demand, carbon, cfg: ElasticityConfig,
+                         interval_s: float = 300.0,
+                         record: bool = False,
+                         budget_series=None,
+                         carbon_forecast=None) -> ElasticResult:
+    """JAX port of `repro.core.elasticity.simulate_elastic`.
+
+    demand : (T, N) demand rate (host array)
+    carbon : dense (T, N), or `(region_mat (T, R), codes (T, N))` for
+             the placed-fleet indexed layout
+    With `record=False` the per-epoch levels are not streamed out
+    (`ElasticResult.levels` is empty) — the summary totals still
+    include them via an in-scan accumulator.
+    `budget_series` overrides the per-epoch budgets (see
+    `simulate_elastic`); when omitted and `cfg.shape_budget` is set it
+    is derived host-side from the mean-over-containers carbon signal,
+    matching the NumPy backend bit for bit.
+    `carbon_forecast` overrides the matrix the carbon forecaster runs
+    on — the scaler then plans against that signal while billing
+    `carbon` (the observed/true split under signal-plane faults):
+    (T, R) region form in indexed mode, (T, N) dense otherwise. The
+    fleet backend forecasts the very same matrix host-side, so the two
+    stay bit-identical (forecast-then-gather on both).
+    """
+    with obs.span("elastic.prepare"):
+        xs, T, n, R = _prepare(demand, carbon, cfg, interval_s,
+                               budget_series, carbon_forecast)
+    dt = float(interval_s)
     key = (_spec_key(cfg, dt), T, n, R, bool(record))
     fn = _SCAN_CACHE.get(key)
     with jax.enable_x64(True):
         if fn is None:
             fn = _build_scan(cfg, dt, n, R, record)
             _SCAN_CACHE[key] = fn
-        dev = jax.devices()[0]
-        xs_dev = tuple(jax.device_put(a, dev) for a in xs)
-        (prev, backlog, scal), ys = fn(xs_dev)
-        served_rate = np.asarray((ys[0] if record else ys))
-        levels = (np.asarray(ys[1], dtype=np.int64) if record
-                  else np.zeros((0, n), dtype=np.int64))
-        backlog = np.asarray(backlog)
-        scal = np.asarray(scal)
+        with obs.span("elastic.h2d"):
+            xs_dev = jax.block_until_ready(
+                jax.device_put(xs, jax.devices()[0]))
+            obs.count("h2d_bytes", obs.nbytes(xs_dev))
+        with obs.span("elastic.wait"):
+            (prev, backlog, scal), ys = jax.block_until_ready(fn(xs_dev))
+        with obs.span("elastic.d2h"):
+            got = jax.device_get((ys, backlog, scal))
+            obs.count("d2h_bytes", obs.nbytes(got))
+            ys, backlog, scal = got
+            served_rate = ys[0] if record else ys
+            levels = (np.asarray(ys[1], dtype=np.int64) if record
+                      else np.zeros((0, n), dtype=np.int64))
+    level_epochs = int(round(float(scal[3])))
+    obs.count("elastic_level_epochs", level_epochs)
 
     return ElasticResult(levels=levels, served_w=served_rate * dt,
-                         offered_w=demand * dt, backlog=backlog,
+                         offered_w=xs[0] * dt, backlog=backlog,
                          est_emissions_g=float(scal[0]),
                          emissions_g=float(scal[1]),
                          cap_violations=int(round(float(scal[2]))),
-                         interval_s=dt,
-                         level_epochs=int(round(float(scal[3]))))
+                         interval_s=dt, level_epochs=level_epochs)

@@ -758,17 +758,20 @@ def _prepare_sweep_inputs(traces, carbon, targets, cfg_base, demand_scale,
             placement = copy.copy(placement)
             placement.regions = raw * shock_mult
         if faults is not None:
+            from repro import obs
             from repro.robustness.degrade import observe_intensity
             from repro.robustness.faults import power_gap_vector
-            # TRUE regional signal (post grid shocks — those are
-            # physical); the controller plane sees the degraded feed
-            true_reg = placement._region_matrix(T)
-            signal = observe_intensity(true_reg, faults,
-                                       cfg_base.interval_s)
-            placement = copy.copy(placement)
-            placement.regions = signal.observed
-            fault_ctx = _FaultContext(signal, signal.observed, true_reg,
-                                      power_gap_vector(faults, T), faults)
+            with obs.span("sweep.faults"):
+                # TRUE regional signal (post grid shocks — those are
+                # physical); the controller plane sees the degraded feed
+                true_reg = placement._region_matrix(T)
+                signal = observe_intensity(true_reg, faults,
+                                           cfg_base.interval_s)
+                placement = copy.copy(placement)
+                placement.regions = signal.observed
+                fault_ctx = _FaultContext(signal, signal.observed, true_reg,
+                                          power_gap_vector(faults, T),
+                                          faults)
         demand_plan = stack
         if demand_scale is not None and np.any(
                 np.asarray(demand_scale) != 1.0):

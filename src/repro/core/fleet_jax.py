@@ -1142,31 +1142,7 @@ def sweep_population_jax(policies: dict, family: SliceFamily, traces,
 
     traffic_summary = None
     run_traffic = None
-    mod_cols = None
     T = demand_one.shape[0]
-    if traffic is not None:
-        from repro.traffic.sim_jax import TrafficSpec
-        with obs.span("sweep.traffic"):
-            arr, tres = _prepare_traffic(traffic, plan, T,
-                                         cfg_base.interval_s)
-        traffic_summary = tres.summary()
-        if elasticity is None:
-            # the in-scan traffic_step fold drives the demand modulation
-            # on device; the serving-ledger row metrics come from the
-            # (tiny, (T, R)) NumPy pipeline — parity between the two is
-            # pinned <=1e-6 by the jax traffic tests
-            run_traffic = (TrafficSpec.from_config(traffic,
-                                                   cfg_base.interval_s),
-                           arr.requests)
-        if elasticity is not None or energy is not None:
-            # the host-side compact pipeline (energy supply load,
-            # elasticity forecasters) needs the modulation as host
-            # floats — same gather as the fleet backend (with
-            # elasticity this also keeps the level counts exact, not
-            # just 1e-6-close)
-            mod = tres.demand_mod(traffic.demand_gain)
-            mod_cols = mod[np.arange(T)[:, None], plan.assign[:T]]
-
     # compact host pipeline, pinned layer order (see the fleet backend):
     # demand_scale -> traffic -> energy -> elasticity
     comp = None
@@ -1175,8 +1151,28 @@ def sweep_population_jax(policies: dict, family: SliceFamily, traces,
         if demand_scale is not None and np.any(
                 np.asarray(demand_scale) != 1.0):
             comp = comp * demand_scale
-        if mod_cols is not None:
-            comp = comp * mod_cols
+    if traffic is not None:
+        from repro.traffic.sim_jax import TrafficSpec
+        with obs.span("sweep.traffic"):
+            arr, tres = _prepare_traffic(traffic, plan, T,
+                                         cfg_base.interval_s)
+            traffic_summary = tres.summary()
+            if elasticity is None:
+                # the in-scan traffic_step fold drives the demand modulation
+                # on device; the serving-ledger row metrics come from the
+                # (tiny, (T, R)) NumPy pipeline — parity between the two is
+                # pinned <=1e-6 by the jax traffic tests
+                run_traffic = (TrafficSpec.from_config(traffic,
+                                                       cfg_base.interval_s),
+                               arr.requests)
+            if comp is not None:
+                # the host-side compact pipeline (energy supply load,
+                # elasticity forecasters) needs the modulation as host
+                # floats — same gather as the fleet backend (with
+                # elasticity this also keeps the level counts exact, not
+                # just 1e-6-close)
+                mod = tres.demand_mod(traffic.demand_gain)
+                comp = comp * mod[np.arange(T)[:, None], plan.assign[:T]]
 
     energy_summary = None
     run_energy = None
@@ -1191,33 +1187,33 @@ def sweep_population_jax(policies: dict, family: SliceFamily, traces,
                 energy, family, plan, comp, T, cfg_base.interval_s,
                 grid_up, region_mat=(fault_ctx.true_reg
                                      if fault_ctx is not None else None))
-        energy_summary = sres.summary()
-        if elasticity is None:
-            # in-scan fold: the scan re-derives the supply ledger on
-            # device from the (traffic-modulated) demand and applies
-            # cap/c_eff per epoch; the energy_* row metrics above come
-            # from the shared host simulation (the two agree <=1e-6,
-            # pinned by the energy tests). Under faults the raw
-            # observed grid rides along as obs_mat and the step scales
-            # it onto the delivered mix by the observed/true ratio.
-            run_energy = (spec_e, solar_mat, grid_up)
-        else:
-            # with elasticity downstream the cap must land *before* the
-            # demand forecasters — host-applied, same floats as the
-            # fleet backend; billing (and the carbon forecast) switch
-            # to the delivered mix's effective intensity
-            comp = comp * cap_cols
-            carbon = (sres.c_eff, codes)
-            if fault_ctx is not None:
-                # observed delivered mix: true effective intensity
-                # scaled by the per-region observed/true grid ratio —
-                # same host floats as the fleet backend
-                tr = fault_ctx.true_reg[:T]
-                safe = np.where(tr > 0.0, tr, 1.0)
-                ratio = np.where(tr > 0.0,
-                                 fault_ctx.obs_reg[:T] / safe, 1.0)
-                carbon_obs = sres.c_eff * ratio
-                ela_forecast = carbon_obs
+            energy_summary = sres.summary()
+            if elasticity is None:
+                # in-scan fold: the scan re-derives the supply ledger on
+                # device from the (traffic-modulated) demand and applies
+                # cap/c_eff per epoch; the energy_* row metrics above come
+                # from the shared host simulation (the two agree <=1e-6,
+                # pinned by the energy tests). Under faults the raw
+                # observed grid rides along as obs_mat and the step scales
+                # it onto the delivered mix by the observed/true ratio.
+                run_energy = (spec_e, solar_mat, grid_up)
+            else:
+                # with elasticity downstream the cap must land *before* the
+                # demand forecasters — host-applied, same floats as the
+                # fleet backend; billing (and the carbon forecast) switch
+                # to the delivered mix's effective intensity
+                comp = comp * cap_cols
+                carbon = (sres.c_eff, codes)
+                if fault_ctx is not None:
+                    # observed delivered mix: true effective intensity
+                    # scaled by the per-region observed/true grid ratio —
+                    # same host floats as the fleet backend
+                    tr = fault_ctx.true_reg[:T]
+                    safe = np.where(tr > 0.0, tr, 1.0)
+                    ratio = np.where(tr > 0.0,
+                                     fault_ctx.obs_reg[:T] / safe, 1.0)
+                    carbon_obs = sres.c_eff * ratio
+                    ela_forecast = carbon_obs
 
     elastic_summary = None
     if elasticity is not None:
@@ -1233,15 +1229,17 @@ def sweep_population_jax(policies: dict, family: SliceFamily, traces,
         with obs.span("sweep.elastic_budget"):
             budget = _elastic_budget_series(plan, T, elasticity,
                                             cfg_base.interval_s)
-        # the elasticity scan gathers on the host: the plan's host codes
-        eres = simulate_elastic_jax(comp, (carbon[0], plan.assign),
-                                    elasticity,
-                                    cfg_base.interval_s,
-                                    budget_series=budget,
-                                    carbon_forecast=ela_forecast)
-        demand_one = eres.demand_served()
+        with obs.span("sweep.elastic"):
+            # the elasticity scan gathers on the host: the plan's host
+            # codes
+            eres = simulate_elastic_jax(comp, (carbon[0], plan.assign),
+                                        elasticity,
+                                        cfg_base.interval_s,
+                                        budget_series=budget,
+                                        carbon_forecast=ela_forecast)
+            demand_one = eres.demand_served()
+            elastic_summary = eres.summary()
         demand_scale = 1.0          # already applied ahead of the layer
-        elastic_summary = eres.summary()
 
     # the planner's device demand stands in for a push where the run
     # would push the very array the plan was given: not after

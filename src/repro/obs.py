@@ -16,9 +16,20 @@ equality):
           plan.d2h           its carry and rounds pulled, the
                              `PlacementPlan` built (the assignments stay
                              on the device)
-      sweep.traffic          `fleet._prepare_traffic`
-      sweep.energy           `fleet._prepare_energy`
+        sweep.faults         the observed carbon feed (`observe_intensity`)
+                             and the power-meter gaps, under a fault plan
+      sweep.traffic          `fleet._prepare_traffic`, its row readings and
+                             the demand factor of each container's region
+      sweep.energy           `fleet._prepare_energy`, its row readings and
+                             the cap and intensities handed on
       sweep.elastic_budget   `fleet._elastic_budget_series`
+      sweep.elastic          the elasticity layer, its served demand and
+                             row readings
+        elastic.prepare      `elasticity_jax.simulate_elastic_jax`: the
+                             demand and carbon forecasts and the budgets
+        elastic.h2d          its inputs pushed to the device
+        elastic.wait         the elasticity scan, dispatched and awaited
+        elastic.d2h          the served demand, backlog and totals pulled
       fleet.prepare          host inputs of `FleetSimulatorJax.run`
       fleet.h2d              its host arguments pushed, per shard (the
                              planner's device arrays are taken up)
@@ -35,6 +46,8 @@ opens; `last_sweep()` returns a copy:
                              device arrays (codes, demand) in place of a
                              push from the host
     admission_rounds         preference rounds of the plan, over its epochs
+    elastic_level_epochs     resource levels the elasticity scan allocated,
+                             summed over its epochs and containers
 """
 from __future__ import annotations
 
@@ -42,7 +55,8 @@ import contextlib
 
 import jax
 
-COUNTERS = ("h2d_bytes", "d2h_bytes", "handoff_bytes", "admission_rounds")
+COUNTERS = ("h2d_bytes", "d2h_bytes", "handoff_bytes", "admission_rounds",
+            "elastic_level_epochs")
 
 span = jax.profiler.TraceAnnotation
 

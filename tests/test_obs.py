@@ -99,8 +99,10 @@ def test_every_placed_span_once_per_sweep_inside_it(traced):
     for name in PLAN_PARTS:
         for e in _named(events, name):
             assert any(_inside(e, p) for p in _named(events, "plan")), name
-    # off this path: no traffic, energy or elasticity spans
-    for name in ("sweep.traffic", "sweep.energy", "sweep.elastic_budget"):
+    # off this path: no traffic, energy, elasticity or fault spans
+    for name in ("sweep.traffic", "sweep.energy", "sweep.elastic_budget",
+                 "sweep.faults", "sweep.elastic", "elastic.prepare",
+                 "elastic.h2d", "elastic.wait", "elastic.d2h"):
         assert _named(events, name) == []
 
 
@@ -214,3 +216,79 @@ def test_counters_start_from_zero_with_each_sweep():
     # a copy: changing it changes nothing kept
     second["h2d_bytes"] = 9
     assert obs.last_sweep()["h2d_bytes"] == 0
+
+
+# --- the all-layer path: faults, elasticity ---------------------------
+
+ELASTIC = ("elastic.prepare", "elastic.h2d", "elastic.wait", "elastic.d2h")
+
+
+def _layered_sweep():
+    from repro.core.elasticity import ElasticityConfig
+    from repro.robustness import CarbonFeedFaults, FaultPlan
+    traces = [t.util for t in sample_population(N_TR, days=1, seed=5)]
+    return sweep_population(
+        {"cc": lambda: CarbonContainerPolicy("energy")}, paper_family(),
+        traces, None, list(TARGETS), SimConfig(target_rate=0.0),
+        backend="jax", placement=_engine(N_TR),
+        elasticity=ElasticityConfig(k_levels=2, unit_capacity=0.3,
+                                    budget_g_per_epoch=2.5 * N_TR),
+        faults=FaultPlan(carbon=CarbonFeedFaults(dropout_prob=0.2), seed=3))
+
+
+@pytest.fixture(scope="module")
+def traced_layers(tmp_path_factory):
+    off = _layered_sweep()
+    tdir = str(tmp_path_factory.mktemp("trace_layers"))
+    with jax.profiler.trace(tdir):
+        on = _layered_sweep()
+    return off, on, _host_events(tdir)
+
+
+def test_elastic_and_fault_spans_once_per_sweep_inside_it(traced_layers):
+    _, _, events = traced_layers
+    (root,) = _named(events, "sweep")
+    for name in ELASTIC + ("sweep.faults",):
+        assert len([e for e in _named(events, name) if _inside(e, root)]) \
+            == 1, name
+    (faults,) = _named(events, "sweep.faults")
+    assert _inside(faults, _named(events, "sweep.prepare")[0])
+    (layer,) = _named(events, "sweep.elastic")
+    assert all(_inside(e, layer) for n in ELASTIC for e in _named(events, n))
+    iv = sorted((e[1], e[2]) for n in ELASTIC for e in _named(events, n))
+    assert all(a[1] <= b[0] for a, b in zip(iv, iv[1:]))
+    # the scan runs after the budget is made and before the fleet scan
+    (budget,) = _named(events, "sweep.elastic_budget")
+    (fleet,) = _named(events, "fleet.prepare")
+    assert budget[2] <= iv[0][0] and iv[-1][1] <= fleet[1]
+
+
+def test_rows_with_the_profiler_on_equal_rows_with_it_off_all_layers(
+        traced_layers):
+    off, on, _ = traced_layers
+    assert on == off
+
+
+def test_elastic_counters_are_the_arrays_moved():
+    from repro.core.elasticity import ElasticityConfig
+    from repro.core.elasticity_jax import simulate_elastic_jax
+    T, n, R = 288, 7, len(REGIONS)
+    rng = np.random.default_rng(2)
+    demand = rng.uniform(0.0, 1.0, (T, n))
+    region = rng.uniform(100.0, 700.0, (T, R))
+    codes = rng.integers(0, R, (T, n))
+    cfg = ElasticityConfig(k_levels=3, unit_capacity=0.3,
+                           budget_g_per_epoch=4.0)
+    with obs.sweep():
+        res = simulate_elastic_jax(demand, (region, codes), cfg)
+        counts = obs.last_sweep()
+    f8, i4 = 8, 4
+    up = (2 * T * n * f8              # demand, its forecast
+          + T * f8                    # budgets
+          + T * n * i4                # region codes
+          + 2 * T * R * f8)           # region carbon, its forecast
+    down = T * n * f8 + n * f8 + 4 * f8     # served, backlog, totals
+    assert counts["h2d_bytes"] == up
+    assert counts["d2h_bytes"] == down
+    assert counts["elastic_level_epochs"] == res.level_epochs
+    assert 0 < res.level_epochs == res.summary()["elastic_level_epochs"]
