@@ -1,7 +1,7 @@
 """JAX's persistent compilation cache, kept at one fixed place.
 
 A cold run of the fleet sweep spends minutes compiling (the elasticity
-scan's argsort over f64 scores dominates), so every entry point turns
+scan's sorts over f64 scores dominate), so every entry point turns
 the persistent cache on before its first compile. The cache key
 includes the directory, so the directory never moves: it is
 ``$JAX_COMPILATION_CACHE_DIR`` when that is set (JAX reads the variable

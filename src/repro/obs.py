@@ -48,6 +48,8 @@ opens; `last_sweep()` returns a copy:
     admission_rounds         preference rounds of the plan, over its epochs
     elastic_level_epochs     resource levels the elasticity scan allocated,
                              summed over its epochs and containers
+    elastic_cut_epochs       epochs in which the elasticity budget left at
+                             least one optional level out
 """
 from __future__ import annotations
 
@@ -56,7 +58,7 @@ import contextlib
 import jax
 
 COUNTERS = ("h2d_bytes", "d2h_bytes", "handoff_bytes", "admission_rounds",
-            "elastic_level_epochs")
+            "elastic_level_epochs", "elastic_cut_epochs")
 
 span = jax.profiler.TraceAnnotation
 

@@ -16,33 +16,67 @@ from repro.core.fleet_jax import ensure_cpu_xla_flags  # noqa: E402
 
 ensure_cpu_xla_flags()
 
+from repro import obs  # noqa: E402
+from repro.carbon.forecast import forecast_series  # noqa: E402
 from repro.core.elasticity import (ElasticityConfig,  # noqa: E402
                                    simulate_elastic)
-from repro.core.elasticity_jax import simulate_elastic_jax  # noqa: E402
+from repro.core.elasticity_jax import (_build_scan,  # noqa: E402
+                                       _prepare, simulate_elastic_jax)
 
 TOL = 1e-6
 CFG = dict(k_levels=4, unit_capacity=1.5, base_w=50.0, peak_w=200.0,
            min_level=1, max_step=1)
+FMODE = {"oracle": "oracle", "persistence": "persistence",
+         "forecast": "diurnal_ar1"}
+# budgets (g per epoch) at _inputs' size: none, binding in all but the
+# zero-intensity epochs, binding in some epochs and not in others
+BUDGETS = [None, 2.0, 100.0]
 
 
-def _inputs(T=48, N=12, R=3, seed=0):
+def _inputs(T=48, N=12, R=3, seed=0, case="random"):
+    """Demand and carbon; `case` "ties" gives the second half of the
+    containers the first half's demand and regions (equal scores, so the
+    greedy's stable order decides between them), "zero_gram" zeroes the
+    carbon in every fourth epoch (levels that cost nothing)."""
     rng = np.random.default_rng(seed)
     demand = np.abs(rng.normal(3.0, 1.5, (T, N)))
     region_mat = np.abs(rng.normal(300.0, 150.0, (T, R)))
     region_mat[5] = 0.0                      # zero-intensity epoch
     codes = rng.integers(0, R, (T, N)).astype(np.int32)
+    if case == "ties":
+        demand[:, N // 2:] = demand[:, :N - N // 2]
+        codes[:, N // 2:] = codes[:, :N - N // 2]
+    elif case == "zero_gram":
+        region_mat[::4] = 0.0
     dense = region_mat[np.arange(T)[:, None], codes]
     return demand, region_mat, codes, dense
 
 
-@pytest.mark.parametrize("budget", [None, 2.0])
+def _numpy_indexed(demand, region_mat, codes, cfg, dt):
+    """The NumPy backend on the indexed layout: carbon forecast on the
+    (T, R) region matrix, then gathered, as the JAX scan does."""
+    T = demand.shape[0]
+    period = max(1, int(round(24 * 3600.0 / dt)))
+    chat = forecast_series(region_mat, FMODE[cfg.forecast],
+                           period_steps=period, rho=cfg.rho)
+    rows = np.arange(T)[:, None]
+    return simulate_elastic(demand, region_mat[rows, codes], cfg, dt,
+                            carbon_forecast=chat[rows, codes])
+
+
+@pytest.mark.parametrize("case", ["random", "ties", "zero_gram"])
+@pytest.mark.parametrize("budget", BUDGETS)
 @pytest.mark.parametrize("mode", ["oracle", "persistence", "forecast"])
-def test_jax_matches_numpy_dense_and_indexed(mode, budget):
-    demand, region_mat, codes, dense = _inputs()
+def test_jax_matches_numpy_dense_and_indexed(mode, budget, case):
+    demand, region_mat, codes, dense = _inputs(case=case)
     cfg = ElasticityConfig(budget_g_per_epoch=budget, forecast=mode, **CFG)
-    a = simulate_elastic(demand, dense, cfg, 300.0)
-    for carbon in (dense, (region_mat, codes)):
-        b = simulate_elastic_jax(demand, carbon, cfg, 300.0, record=True)
+    refs = (simulate_elastic(demand, dense, cfg, 300.0),
+            _numpy_indexed(demand, region_mat, codes, cfg, 300.0))
+    for a, carbon in zip(refs, (dense, (region_mat, codes))):
+        with obs.sweep():
+            b = simulate_elastic_jax(demand, carbon, cfg, 300.0,
+                                     record=True)
+            cut = obs.last_sweep()["elastic_cut_epochs"]
         np.testing.assert_array_equal(a.levels, b.levels)
         scale = max(float(np.max(np.abs(a.served_w))), 1.0)
         assert np.max(np.abs(a.served_w - b.served_w)) <= TOL * scale
@@ -51,6 +85,14 @@ def test_jax_matches_numpy_dense_and_indexed(mode, budget):
         assert a.cap_violations == b.cap_violations
         assert a.summary()["elastic_level_epochs"] \
             == b.summary()["elastic_level_epochs"]
+        # the budgets bind as described at BUDGETS
+        T = demand.shape[0]
+        if budget is None:
+            assert cut == 0
+        elif budget == BUDGETS[1]:
+            assert cut >= T // 2
+        else:
+            assert 0 < cut < T
 
 
 def test_record_false_summary_matches_record_true():
@@ -137,3 +179,19 @@ def test_shape_validation():
         simulate_elastic_jax(demand, (region_mat[:10], codes), cfg)
     with pytest.raises(ValueError):
         simulate_elastic_jax(demand, np.zeros((4, 4)), cfg)
+
+
+@pytest.mark.parametrize("layout", ["dense", "indexed"])
+def test_budgeted_scan_has_no_gather_or_scatter(layout):
+    # the greedy maps its order back to containers by sorting; a TPU
+    # runs gathers and scatters over the (N·K,) levels one element at a
+    # time, so neither may come back into the program
+    demand, region_mat, codes, dense = _inputs()
+    carbon = dense if layout == "dense" else (region_mat, codes)
+    cfg = ElasticityConfig(budget_g_per_epoch=2.0, **CFG)
+    xs, T, n, R = _prepare(demand, carbon, cfg, 300.0, None, None)
+    with jax.enable_x64(True):
+        text = _build_scan(cfg, 300.0, n, R, False).lower(xs).as_text()
+    assert text.count("stablehlo.sort") == 2
+    assert "gather" not in text
+    assert "scatter" not in text

@@ -287,8 +287,50 @@ def test_elastic_counters_are_the_arrays_moved():
           + T * f8                    # budgets
           + T * n * i4                # region codes
           + 2 * T * R * f8)           # region carbon, its forecast
-    down = T * n * f8 + n * f8 + 4 * f8     # served, backlog, totals
+    down = T * n * f8 + n * f8 + 5 * f8     # served, backlog, totals
     assert counts["h2d_bytes"] == up
     assert counts["d2h_bytes"] == down
     assert counts["elastic_level_epochs"] == res.level_epochs
     assert 0 < res.level_epochs == res.summary()["elastic_level_epochs"]
+
+
+def _numpy_cut_epochs(demand, carbon, cfg, monkeypatch):
+    """Epochs in which the NumPy backend allocated fewer levels than the
+    containers desired (the budget left an optional level out)."""
+    import dataclasses
+
+    from repro.core import elasticity
+    free = dataclasses.replace(cfg, budget_g_per_epoch=None,
+                               shape_budget=False)
+    alloc, cuts = elasticity.allocate_epoch, []
+
+    def counted(want, chat, prev, cfg, dt, budget_g=None):
+        got, lo = alloc(want, chat, prev, cfg, dt, budget_g=budget_g)
+        desired, _ = alloc(want, chat, prev, free, dt)
+        cuts.append(bool(got.sum() < desired.sum()))
+        return got, lo
+
+    monkeypatch.setattr(elasticity, "allocate_epoch", counted)
+    elasticity.simulate_elastic(demand, carbon, cfg)
+    return sum(cuts)
+
+
+@pytest.mark.parametrize("budget,binds", [(None, "never"), (4.0, "always"),
+                                          (80.0, "sometimes"),
+                                          (400.0, "never")])
+def test_elastic_cut_epochs_are_the_numpy_backends_cuts(budget, binds,
+                                                        monkeypatch):
+    from repro.core.elasticity import ElasticityConfig
+    from repro.core.elasticity_jax import simulate_elastic_jax
+    T, n = 96, 7
+    rng = np.random.default_rng(3)
+    demand = rng.uniform(0.0, 1.0, (T, n))
+    carbon = rng.uniform(100.0, 700.0, (T, n))
+    cfg = ElasticityConfig(k_levels=3, unit_capacity=0.3,
+                           budget_g_per_epoch=budget, forecast="oracle")
+    with obs.sweep():
+        simulate_elastic_jax(demand, carbon, cfg)
+        got = obs.last_sweep()["elastic_cut_epochs"]
+    assert got == _numpy_cut_epochs(demand, carbon, cfg, monkeypatch)
+    assert {"never": got == 0, "always": got == T,
+            "sometimes": 0 < got < T}[binds], got
